@@ -3,7 +3,7 @@
 * ``dbscan_ref`` — classic BFS DBSCAN in NumPy (the oracle; O(n^2) with
   blockwise distance computation, matching the paper's complexity model).
 * ``dbscan`` — TPU-native JAX version: ε-neighbour counts and min-label
-  propagation are blocked matmuls (kernels/pairwise_dist.py), cluster
+  propagation are fused distance tiles (kernels/pairwise_dist.py), cluster
   labels converge by fixed-point iteration under ``lax.while_loop``.
 
 Two composed optimisations make the JAX version near-linear on clustered
@@ -192,22 +192,14 @@ def dbscan(
     """
     assert block_sparse in ("never", "auto", "always"), block_sparse
     n = points.shape[0]
-    # Centre on the masked bbox midpoint: d2 is translation-invariant, but
-    # the kernels' xx+yy-2xy expansion is cancellation-prone — at coord
-    # magnitude ~100 its f32 error rivals eps² and could disagree with the
-    # (difference-based, accurate) bbox pruning near the eps boundary.
-    # Centring both paths keeps them bit-identical to each other and
-    # accurate at any offset.  Masked rows are zeroed so padding never
-    # carries large values into the tiles.
-    big = jnp.float32(3.4e38)
-    lo = jnp.min(jnp.where(mask[:, None], points, big), axis=0)
-    hi = jnp.max(jnp.where(mask[:, None], points, -big), axis=0)
-    center = jnp.where(hi >= lo, (lo + hi) * 0.5, 0.0)
-    points = jnp.where(mask[:, None], points - center, 0.0)
+    # Masked rows are zeroed so padding never carries stale values into
+    # the tiles.  The kernels' difference-form d2 is exact at any
+    # coordinate offset (DESIGN.md §4 item 6).
+    points = jnp.where(mask[:, None], points, 0.0)
     doubling_steps = max(1, math.ceil(math.log2(max(n, 2)))) if pointer_doubling else 0
     # "auto" engages the sparse path only with enough points for several
     # tiles AND a Pallas backend — on pure-jnp reference backends the
-    # sparse fold is sequential, so dense matmuls are the faster CPU path.
+    # sparse fold is sequential, so dense tiles are the faster CPU path.
     use_sparse_path = block_sparse == "always" or (
         block_sparse == "auto" and n >= 2 * bt and ops.use_pallas_backend()
     )

@@ -38,10 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import dbscan as dbscan_mod
 from repro.core import geometry, kmeans
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 SENTINEL = 2**30
 
@@ -240,7 +239,7 @@ def cross_min_d2(ca: jax.Array, cnta: jax.Array, va: jax.Array,
     pts = ca.astype(jnp.float32)
 
     def row(i):
-        d2 = jnp.sum((pts[i][:, None, :] - flat[None, :, :]) ** 2, axis=-1)
+        d2 = ref.diff_d2(pts[i], flat.T)
         d2 = jnp.where(pa[i][:, None] & pb[None, :], d2, geometry.BIG)
         return jnp.min(d2.reshape(v, b, v), axis=(0, 2))        # (B,)
 
@@ -251,13 +250,12 @@ def cross_min_d2(ca: jax.Array, cnta: jax.Array, va: jax.Array,
 def contour_pair_d2_exact(batch: ClusterSet, cfg: DDCConfig) -> jax.Array:
     """``contour_pair_d2`` in the difference form on every backend.
 
-    The kernel path behind ``contour_pair_d2`` matches the reference only
-    within tolerance on TPU (centred MXU expansion), while the delta
-    patches (``update_pair_d2``) are always difference-form — mixing the
-    two in one cached matrix would break the streaming engine's
-    bit-exactness contract near the merge threshold.  The engine
-    therefore builds its full matrix here: same math, backend-stable, and
-    bit-identical to the rows ``cross_min_d2`` patches in later."""
+    The streaming engine's cached matrix is patched row by row through
+    ``cross_min_d2`` (``update_pair_d2``), so it builds the full matrix
+    through the same function: every entry, full or patched, then comes
+    from one expression on every backend, and bit-identity near the merge
+    threshold does not rest on the kernel and ``cross_min_d2`` rounding
+    alike."""
     c, v = cfg.max_clusters, cfg.max_verts
     m = batch.valid.shape[0] * c
     contours = batch.contours.reshape(m, v, 2)
@@ -542,7 +540,7 @@ def merge_sync(cs: ClusterSet, cfg: DDCConfig, axis: str,
     lane: (K−1)·B.  Returns (global ClusterSet, local-slot → global-slot
     map (C,)).
     """
-    k = compat.axis_size(axis)
+    k = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     if meter is not None:
         meter.add_collective(k * (k - 1), _wire_bytes(cs))
@@ -560,7 +558,7 @@ def merge_async(cs: ClusterSet, cfg: DDCConfig, axis: str,
     in XLA's schedule.  Matches the paper's asynchronous model (merge as
     soon as the partner is ready).  Collective bytes per lane: log2(K)·B.
     """
-    k = compat.axis_size(axis)
+    k = jax.lax.axis_size(axis)
     assert k & (k - 1) == 0, f"async schedule needs power-of-two shards, got {k}"
     me = jax.lax.axis_index(axis)
     my_map = jnp.arange(cfg.max_clusters, dtype=jnp.int32)
@@ -598,7 +596,7 @@ def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
     (K-1)·B all-gather and async's log2(K)·B butterfly.  Unlike the
     butterfly, non-leaders idle above their level (the paper's Fig. 1).
     """
-    k = compat.axis_size(axis)
+    k = jax.lax.axis_size(axis)
     d = cfg.tree_degree
     me = jax.lax.axis_index(axis)
     my_map = jnp.where(cs.valid, jnp.arange(cfg.max_clusters, dtype=jnp.int32), -1)
@@ -725,7 +723,7 @@ def make_ddc_fn(mesh, axis: str, cfg: DDCConfig, meter: CommMeter | None = None)
 
     @jax.jit
     def run(points, mask):
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             lambda p, m: ddc_shard(p, m, cfg, axis, meter=meter),
             mesh=mesh,
             in_specs=(P(axis, None), P(axis)),

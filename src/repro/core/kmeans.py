@@ -58,7 +58,8 @@ def kmeans(
         d2 = jnp.where(mask[:, None], d2, 0.0)
         labels = jnp.argmin(d2, axis=1)
         onehot = jax.nn.one_hot(labels, k, dtype=points.dtype) * mask[:, None]
-        sums = onehot.T @ points                            # (k, d)
+        sums = jnp.matmul(onehot.T, points,                 # (k, d)
+                          precision=jax.lax.Precision.HIGHEST)
         cnts = jnp.sum(onehot, axis=0)[:, None]
         new = jnp.where(cnts > 0, sums / jnp.maximum(cnts, 1), cents)
         return new, None

@@ -400,19 +400,14 @@ class JitBackend(_BufferedBatchBackend):
         ((n, 2) + (n,) mask, sharded over the mesh).  Exposed for the
         benchmarks/dry-runs that lower + compile it explicitly;
         ``n_points`` must be a multiple of ``shards``."""
-        import jax
-
         from repro.launch import mesh as mesh_mod
 
         k = self.cfg.shards
         if n_points % k:
             raise ConfigError(f"n_points {n_points} not a multiple of shards {k}")
-        if len(jax.devices()) < k:
-            raise ConfigError(
-                f"jit backend needs >= {k} devices but jax sees "
-                f"{len(jax.devices())}; set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={k} before jax "
-                f"initialises (or lower shards)")
+        shortfall = mesh_mod.device_shortfall(k)
+        if shortfall:
+            raise ConfigError(f"jit backend at shards={k} {shortfall}")
         key = n_points
         if key not in self._runners:
             if len(self._runners) >= 4:   # drop stale executables: every

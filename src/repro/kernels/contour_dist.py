@@ -5,16 +5,24 @@ Phase 2 decides which clusters merge by the minimum pairwise distance
 between their contour vertex buffers.  The per-pair formulation (one row
 of clusters at a time against all vertices, ``lax.map``) serialises M
 small reductions; the batched formulation below computes the full
-(M, M) slot×slot proximity matrix in one pallas_call:
+(M, M) slot×slot proximity matrix in one pallas_call.
 
-* contours arrive flattened cluster-major as (M·V, 2) vertices plus an
-  (M·V,) validity vector (padding verts and invalid slots masked out);
-* each grid step loads a (bi·V, 2) row strip and a (bj·V, 2) column
-  strip, computes the (bi·V, bj·V) squared-distance tile with the MXU
-  expansion |x|² + |y|² − 2·x·yᵀ (same centred-d2 machinery as
-  ``pairwise_dist.py`` — callers centre coordinates so the expansion's
-  f32 cancellation error stays far below merge thresholds), and
-* min-reduces the (V, V) sub-blocks to a (bi, bj) output tile.
+Layout (Mosaic's tiling rule: a block's last dim is a multiple of 128 or
+the whole array, its second-to-last a multiple of 8 or the whole array):
+
+* row side — the (M·V, 2) cluster-major vertices and an (M·V, 1) validity
+  column, one (bi·V, …) strip per grid row;
+* column side — the same vertices transposed to (2, V, M) plus a (V, M)
+  validity, so column *slots* sit on lanes: one (…, V, bj) strip per grid
+  column;
+* output — the (bi, bj) tile of the (M, M) matrix.
+
+Each grid step loops over the V column vertices; for column vertex b it
+computes, per row slot, the (V, bj) squared distances from that slot's V
+vertices to vertex b of every column slot, in difference form (the same
+IEEE ops as ``ref.contour_min_d2``, so results are bit-equal to it), and
+keeps a running (V, bj) min per row slot in VMEM.  A final sublane min
+gives each row slot's (1, bj) output row.
 
 Invalid vertices contribute ``BIG``; a slot with no valid vertices gets a
 BIG row/column, which callers treat as "never merges".
@@ -26,26 +34,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEF_BI = 8
-DEF_BJ = 8
+DEF_BJ = 128
 BIG = 1e30
 
 
-def _contour_min_kernel(x_ref, y_ref, xv_ref, yv_ref, o_ref, *, v: int):
-    bi, bj = o_ref.shape
-    x = x_ref[...].astype(jnp.float32)           # (bi*v, 2)
-    y = y_ref[...].astype(jnp.float32)           # (bj*v, 2)
-    xx = jnp.sum(x * x, axis=-1)[:, None]
-    yy = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xx + yy - 2.0 * jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    d2 = jnp.maximum(d2, 0.0)
-    ok = (xv_ref[...] > 0)[:, None] & (yv_ref[...] > 0)[None, :]
-    d2 = jnp.where(ok, d2, BIG)
-    # Min over each cluster pair's (V, V) vertex sub-block.
-    o_ref[...] = jnp.min(d2.reshape(bi, v, bj, v), axis=(1, 3))
+def _contour_min_kernel(x_ref, xv_ref, c_ref, cv_ref, o_ref, acc_ref, *,
+                        v: int):
+    bi = o_ref.shape[0]
+    acc_ref[...] = jnp.full(acc_ref.shape, BIG, jnp.float32)
+
+    def body(b, carry):
+        cx = c_ref[0, pl.ds(b, 1), :]                 # (1, bj)
+        cy = c_ref[1, pl.ds(b, 1), :]
+        cok = cv_ref[pl.ds(b, 1), :] > 0
+        for r in range(bi):
+            rows = slice(r * v, (r + 1) * v)
+            dx = x_ref[rows, 0:1] - cx                # (v, bj)
+            dy = x_ref[rows, 1:2] - cy
+            d2 = dx * dx + dy * dy
+            ok = (xv_ref[rows, :] > 0) & cok
+            acc_ref[rows, :] = jnp.minimum(acc_ref[rows, :],
+                                           jnp.where(ok, d2, BIG))
+        return carry
+
+    jax.lax.fori_loop(0, v, body, 0)
+    for r in range(bi):
+        o_ref[r:r + 1, :] = jnp.min(acc_ref[r * v:(r + 1) * v, :], axis=0,
+                                    keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("v", "bi", "bj", "interpret"))
@@ -55,27 +73,32 @@ def contour_min_d2(
 ) -> jax.Array:
     """Slot×slot min squared contour distance.
 
-    x: (m·v, 2) flattened contour vertices (cluster-major, pre-centred);
-    xv: (m·v,) int32 vertex validity.  m must be a multiple of both ``bi``
-    and ``bj`` (ops.py pads with invalid slots).  Returns (m, m) f32 with
-    BIG where either slot has no valid vertices.
+    x: (m·v, 2) flattened contour vertices (cluster-major); xv: (m·v,)
+    int32 vertex validity.  m must be a multiple of both ``bi`` and
+    ``bj`` (ops.py pads with invalid slots).  Returns (m, m) f32 with BIG
+    where either slot has no valid vertices.
     """
     n, d = x.shape
-    assert n % v == 0, (n, v)
+    assert d == 2 and n % v == 0, (n, d, v)
     m = n // v
     bi = min(bi, m)
     bj = min(bj, m)
     assert m % bi == 0 and m % bj == 0, (m, bi, bj)
+    x = x.astype(jnp.float32)
+    xv = xv.astype(jnp.int32)
+    cols = x.reshape(m, v, 2).transpose(2, 1, 0)      # (2, v, m)
+    cols_valid = xv.reshape(m, v).T                   # (v, m)
     return pl.pallas_call(
         functools.partial(_contour_min_kernel, v=v),
         grid=(m // bi, m // bj),
         in_specs=[
-            pl.BlockSpec((bi * v, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bj * v, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bi * v,), lambda i, j: (i,)),
-            pl.BlockSpec((bj * v,), lambda i, j: (j,)),
+            pl.BlockSpec((bi * v, 2), lambda i, j: (i, 0)),
+            pl.BlockSpec((bi * v, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((2, v, bj), lambda i, j: (0, 0, j)),
+            pl.BlockSpec((v, bj), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bi * v, bj), jnp.float32)],
         interpret=interpret,
-    )(x, x, xv, xv)
+    )(x, xv.reshape(n, 1), cols, cols_valid)

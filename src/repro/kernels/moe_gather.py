@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 DEF_BS = 128
 
@@ -28,7 +27,7 @@ def _gather_kernel(idx_ref, x_hbm, out_ref, scale_ref, *, bs: int, quant: bool):
         row = idx_ref[i]
         valid = row >= 0
         safe = jnp.maximum(row, 0)
-        vals = pl.load(x_hbm, (pl.dslice(safe, 1), slice(None)))[0]
+        vals = x_hbm[pl.ds(safe, 1), :][0]
         vals = jnp.where(valid, vals, 0).astype(jnp.float32)
         if quant:
             absmax = jnp.max(jnp.abs(vals))
@@ -65,7 +64,7 @@ def dispatch_gather(
         grid=(s // bs_,),
         in_specs=[
             pl.BlockSpec((bs_,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((bs_, d), lambda i: (i, 0)),

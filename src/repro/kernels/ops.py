@@ -1,16 +1,17 @@
 """Public kernel entry points.
 
 Each op dispatches to the Pallas TPU kernel on TPU backends and to the
-pure-jnp reference elsewhere (this container is CPU-only; kernels are
-validated in interpret mode by tests/test_kernels.py).  Padding to tile
-multiples happens here so kernels stay shape-strict.
+pure-jnp reference elsewhere.  Off the chip the kernels are checked in
+interpret mode (tests/test_kernels.py) and compiled for a described v5e
+(tests/test_tpu_compile.py); ``chip_smoke.py`` checks them against the
+references on the chip.  Padding to tile multiples happens here so
+kernels stay shape-strict.
 
 Set ``repro.kernels.ops.FORCE`` to "pallas" / "ref" / "interpret" to
 override dispatch (tests use "interpret").
 """
 from __future__ import annotations
 
-import math
 from typing import NamedTuple
 
 import jax
@@ -91,31 +92,23 @@ def min_label_sweep(x, mask, labels, core, eps, *, bn: int = 512, bm: int = 512)
 
 
 def contour_min_d2(contours: jax.Array, counts: jax.Array, valid: jax.Array,
-                   *, bi: int = 8, bj: int = 8) -> jax.Array:
+                   *, bi: int = _cd.DEF_BI, bj: int = _cd.DEF_BJ) -> jax.Array:
     """DDC phase-2 merge matrix: (m, m) min squared distance between every
     pair of padded contour buffers (1e30 where either side is empty).
 
-    contours: (m, v, 2); counts: (m,); valid: (m,) bool.  On the Pallas
-    path coordinates are centred on the valid-vertex bbox midpoint first —
-    d2 is translation-invariant, but the MXU xx+yy−2xy expansion is
-    cancellation-prone (DESIGN.md §4 item 6) and merge thresholds are
-    O(cell²).  The jnp reference uses the difference form directly.
+    contours: (m, v, 2); counts: (m,); valid: (m,) bool.  The kernel and
+    the jnp reference both use the difference form, so they agree to the
+    bit on coordinates at any offset.  The slot axis is padded with
+    invalid slots: to a multiple of ``bi`` while it fits one column strip
+    (the strip is then the whole axis), else to a multiple of ``bj``.
     """
     if not _use_pallas():
         return ref.contour_min_d2(contours, counts, valid)
     m, v, d = contours.shape
-    big = jnp.float32(3.4e38)
-    pts = contours.astype(jnp.float32)
     vert_valid = (jnp.arange(v)[None, :] < counts[:, None]) & valid[:, None]
-    lo = jnp.min(jnp.where(vert_valid[..., None], pts, big), axis=(0, 1))
-    hi = jnp.max(jnp.where(vert_valid[..., None], pts, -big), axis=(0, 1))
-    mid = jnp.where(hi >= lo, 0.5 * (lo + hi), 0.0)
-    flat = (pts - mid).reshape(m * v, d)
+    flat = contours.astype(jnp.float32).reshape(m * v, d)
     fv = vert_valid.reshape(m * v).astype(jnp.int32)
-    # Pad the slot axis with invalid slots up to a tile multiple.
-    bi = min(bi, m)
-    bj = min(bj, m)
-    mult = bi * bj // math.gcd(bi, bj)
+    mult = bi if m <= bj else bj
     pad = (-m) % mult
     if pad:
         flat = jnp.pad(flat, ((0, pad * v), (0, 0)))

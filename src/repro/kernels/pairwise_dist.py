@@ -1,18 +1,26 @@
-"""Pallas TPU kernel: tiled pairwise squared distances + fused ε-neighbour
-counting — the DDC phase-1 hot-spot (DBSCAN region queries).
+"""Pallas TPU kernels: tiled pairwise squared distances + fused ε-neighbour
+counting and min-label sweeps — the DDC phase-1 hot-spot (DBSCAN region
+queries).
 
 The paper's DBSCAN does per-point region queries (pointer chasing).  The
-TPU-native formulation is a blocked matmul: for tiles X (bn, d), Y (bm, d)
+TPU-native formulation is blocked: for a row tile X (bn, d) and a column
+tile Y (bm, d) the kernels compute the (bn, bm) squared-distance tile in
+VMEM and reduce it at once — a per-row neighbour count or a per-row min
+label — so the (n, m) distance matrix never reaches HBM.
 
-    D2 = |X|^2 + |Y|^2 - 2 X Y^T
+Layout (Mosaic accepts only 2-D vector shapes with a lane-dense last
+dimension or a full-array one): row-side operands are columns ((bn, d)
+points, (bn, 1) masks and outputs), column-side operands are rows (Y
+passed transposed as (d, bm), (1, bm) masks, labels and core flags), so
+every broadcast is (bn, 1) against (1, bm).  eps² is one f32 scalar in
+SMEM.  The fused kernels evaluate d² in difference form
+(``ref.diff_d2``): for d = 2 it is four VPU ops per pair, no MXU pass,
+and it rounds identically on every backend, so the kernels are bit-equal
+to their oracles (DESIGN.md §4).
 
-which runs on the MXU.  The fused variant accumulates, per row, the count
-of points within eps — never materialising the (n, m) distance matrix in
-HBM (arithmetic intensity: O(d) flops/byte on the MXU; the count output
-is n int32 instead of n*m floats, so the kernel is compute-bound).
-
-Grid layout: (n // bn, m // bm); the m axis is the innermost (sequential)
-loop so per-row counts accumulate in the output block across j-steps.
+Grid layout (dense): (n // bn, m // bm); the m axis is the innermost
+(sequential) loop so per-row results accumulate in the output block
+across j-steps.
 """
 from __future__ import annotations
 
@@ -23,8 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ref
+
 DEF_BN = 512
 DEF_BM = 512
+NO_LABEL = 2**30
 
 # Active-pair flag bits (see ops.build_tile_pairs): bit0 = pair is real
 # (not tail padding), bit1 = first pair of its row tile (output block must
@@ -32,14 +43,17 @@ DEF_BM = 512
 PAIR_VALID = 1
 PAIR_FIRST = 2
 
+_SMEM_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _dist_kernel(x_ref, y_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
     y = y_ref[...].astype(jnp.float32)
-    xx = jnp.sum(x * x, axis=-1)[:, None]
+    xx = jnp.sum(x * x, axis=-1, keepdims=True)
     yy = jnp.sum(y * y, axis=-1)[None, :]
     d2 = xx + yy - 2.0 * jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     o_ref[...] = jnp.maximum(d2, 0.0)
 
@@ -49,8 +63,8 @@ def pairwise_dist_sq(
     x: jax.Array, y: jax.Array, *, bn: int = DEF_BN, bm: int = DEF_BM,
     interpret: bool = False,
 ) -> jax.Array:
-    """Tiled (n, m) squared-distance matrix.  n, m must be tile-multiples
-    (ops.py pads)."""
+    """Tiled (n, m) squared-distance matrix (MXU expansion, HIGHEST
+    precision).  n, m must be tile-multiples (ops.py pads)."""
     n, d = x.shape
     m = y.shape[0]
     bn = min(bn, n)
@@ -69,22 +83,53 @@ def pairwise_dist_sq(
     )(x, y)
 
 
-def _count_kernel(eps_sq_ref, x_ref, y_ref, xm_ref, ym_ref, o_ref):
-    j = pl.program_id(1)
+def _within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref):
+    """(bn, bm) bool: masked pairs within eps (row masks (bn, 1), column
+    masks (1, bm))."""
+    d2 = ref.diff_d2(x_ref[...], yt_ref[...])
+    return (d2 <= eps_sq_ref[0, 0]) & (xm_ref[...] > 0) & (ym_ref[...] > 0)
 
-    @pl.when(j == 0)
+
+def _count_tile(o_ref, ok):
+    o_ref[...] += jnp.sum(ok.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _min_label_tile(o_ref, ok, lab_ref):
+    labs = jnp.where(ok, lab_ref[...], jnp.int32(NO_LABEL))
+    o_ref[...] = jnp.minimum(o_ref[...], jnp.min(labs, axis=1, keepdims=True))
+
+
+def _count_kernel(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref, o_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    y = y_ref[...].astype(jnp.float32)
-    xx = jnp.sum(x * x, axis=-1)[:, None]
-    yy = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xx + yy - 2.0 * jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    within = (d2 <= eps_sq_ref[0]) & (xm_ref[...] > 0)[:, None] & (ym_ref[...] > 0)[None, :]
-    o_ref[...] += jnp.sum(within.astype(jnp.int32), axis=1)
+    _count_tile(o_ref, _within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref))
+
+
+def _min_label_kernel(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref, lab_ref,
+                      core_ref, o_ref):
+    """One label-propagation sweep tile: o[i] = min(o[i], min_{j in N(i),
+    core j} lab[j])."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.full(o_ref.shape, NO_LABEL, jnp.int32)
+
+    ok = (_within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref)
+          & (core_ref[...] > 0))
+    _min_label_tile(o_ref, ok, lab_ref)
+
+
+def _eps_sq(eps) -> jax.Array:
+    return (jnp.asarray(eps, jnp.float32) ** 2).reshape(1, 1)
+
+
+def _col(v: jax.Array) -> jax.Array:
+    return v.astype(jnp.int32).reshape(-1, 1)
+
+
+def _row(v: jax.Array) -> jax.Array:
+    return v.astype(jnp.int32).reshape(1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
@@ -100,47 +145,21 @@ def neighbor_count(
     bn = min(bn, n)
     bm = min(bm, n)
     assert n % bn == 0 and n % bm == 0, (n, bn, bm)
-    eps_sq = jnp.asarray([jnp.asarray(eps, jnp.float32) ** 2])
-    mask_i = mask.astype(jnp.int32)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _count_kernel,
         grid=(n // bn, n // bm),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SMEM_SCALAR,
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (j,)),
+            pl.BlockSpec((d, bm), lambda i, j: (0, j)),
+            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
-    )(eps_sq, x, x, mask_i, mask_i)
-
-
-def _min_label_kernel(eps_sq_ref, x_ref, y_ref, xm_ref, ym_ref, lab_ref, core_ref, o_ref):
-    """One label-propagation sweep tile: o[i] = min(lab[i], min_{j in N(i), core j} lab[j])."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.full(o_ref.shape, 2**30, jnp.int32)
-
-    x = x_ref[...].astype(jnp.float32)
-    y = y_ref[...].astype(jnp.float32)
-    xx = jnp.sum(x * x, axis=-1)[:, None]
-    yy = jnp.sum(y * y, axis=-1)[None, :]
-    d2 = xx + yy - 2.0 * jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    ok = (
-        (d2 <= eps_sq_ref[0])
-        & (xm_ref[...] > 0)[:, None]
-        & (ym_ref[...] > 0)[None, :]
-        & (core_ref[...] > 0)[None, :]
-    )
-    labs = jnp.where(ok, lab_ref[...][None, :], jnp.int32(2**30))
-    o_ref[...] = jnp.minimum(o_ref[...], jnp.min(labs, axis=1))
+    )(_eps_sq(eps), x, x.T, _col(mask), _row(mask))
+    return out.reshape(n)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
@@ -159,25 +178,23 @@ def min_label_sweep(
     bn = min(bn, n)
     bm = min(bm, n)
     assert n % bn == 0 and n % bm == 0
-    eps_sq = jnp.asarray([jnp.asarray(eps, jnp.float32) ** 2])
     out = pl.pallas_call(
         _min_label_kernel,
         grid=(n // bn, n // bm),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SMEM_SCALAR,
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (j,)),
-            pl.BlockSpec((bm,), lambda i, j: (j,)),
-            pl.BlockSpec((bm,), lambda i, j: (j,)),
+            pl.BlockSpec((d, bm), lambda i, j: (0, j)),
+            pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bm), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        out_specs=pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
-    )(eps_sq, x, x, mask.astype(jnp.int32), mask.astype(jnp.int32),
-      labels.astype(jnp.int32), core.astype(jnp.int32))
-    return out
+    )(_eps_sq(eps), x, x.T, _col(mask), _row(mask), _row(labels), _row(core))
+    return out.reshape(n)
 
 
 # ---------------------------------------------------------------------------
@@ -193,9 +210,8 @@ def min_label_sweep(
 
 
 def _count_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
-                         x_ref, y_ref, xm_ref, ym_ref, o_ref):
-    p = pl.program_id(0)
-    flags = flags_ref[p]
+                         x_ref, yt_ref, xm_ref, ym_ref, o_ref):
+    flags = flags_ref[pl.program_id(0)]
 
     @pl.when((flags & PAIR_FIRST) != 0)
     def _init():
@@ -203,19 +219,48 @@ def _count_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
 
     @pl.when((flags & PAIR_VALID) != 0)
     def _acc():
-        x = x_ref[...].astype(jnp.float32)
-        y = y_ref[...].astype(jnp.float32)
-        xx = jnp.sum(x * x, axis=-1)[:, None]
-        yy = jnp.sum(y * y, axis=-1)[None, :]
-        d2 = xx + yy - 2.0 * jax.lax.dot_general(
-            x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        within = (
-            (d2 <= eps_sq_ref[0])
-            & (xm_ref[...] > 0)[:, None]
-            & (ym_ref[...] > 0)[None, :]
-        )
-        o_ref[...] += jnp.sum(within.astype(jnp.int32), axis=1)
+        _count_tile(o_ref, _within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref))
+
+
+def _min_label_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
+                             x_ref, yt_ref, xm_ref, ym_ref, lab_ref, core_ref,
+                             o_ref):
+    flags = flags_ref[pl.program_id(0)]
+
+    @pl.when((flags & PAIR_FIRST) != 0)
+    def _init():
+        o_ref[...] = jnp.full(o_ref.shape, NO_LABEL, jnp.int32)
+
+    @pl.when((flags & PAIR_VALID) != 0)
+    def _acc():
+        ok = (_within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref)
+              & (core_ref[...] > 0))
+        _min_label_tile(o_ref, ok, lab_ref)
+
+
+def _sparse_call(kernel, n_pairs: int, n: int, d: int, bt: int,
+                 n_col_rows: int, interpret: bool):
+    """pallas_call over the active-pair grid: SMEM eps², the row tile
+    (bt, d), the transposed column tile (d, bt), the row mask (bt, 1) and
+    ``n_col_rows`` column-side (1, bt) operands; output (n, 1)."""
+    col_row = pl.BlockSpec((1, bt), lambda p, r, c, f: (0, c[p]))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_pairs,),
+        in_specs=[
+            _SMEM_SCALAR,
+            pl.BlockSpec((bt, d), lambda p, r, c, f: (r[p], 0)),
+            pl.BlockSpec((d, bt), lambda p, r, c, f: (0, c[p])),
+            pl.BlockSpec((bt, 1), lambda p, r, c, f: (r[p], 0)),
+        ] + [col_row] * n_col_rows,
+        out_specs=pl.BlockSpec((bt, 1), lambda p, r, c, f: (r[p], 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        interpret=interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -233,56 +278,11 @@ def neighbor_count_sparse(
     """
     n, d = x.shape
     assert n % bt == 0, (n, bt)
-    n_pairs = rows.shape[0]
-    eps_sq = jnp.asarray([jnp.asarray(eps, jnp.float32) ** 2])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda p, r, c, f: (0,)),
-            pl.BlockSpec((bt, d), lambda p, r, c, f: (r[p], 0)),
-            pl.BlockSpec((bt, d), lambda p, r, c, f: (c[p], 0)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (r[p],)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (c[p],)),
-        ],
-        out_specs=pl.BlockSpec((bt,), lambda p, r, c, f: (r[p],)),
-    )
-    mask_i = mask.astype(jnp.int32)
-    return pl.pallas_call(
-        _count_sparse_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
-    )(rows, cols, flags, eps_sq, x, x, mask_i, mask_i)
-
-
-def _min_label_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
-                             x_ref, y_ref, xm_ref, ym_ref, lab_ref, core_ref,
-                             o_ref):
-    p = pl.program_id(0)
-    flags = flags_ref[p]
-
-    @pl.when((flags & PAIR_FIRST) != 0)
-    def _init():
-        o_ref[...] = jnp.full(o_ref.shape, 2**30, jnp.int32)
-
-    @pl.when((flags & PAIR_VALID) != 0)
-    def _acc():
-        x = x_ref[...].astype(jnp.float32)
-        y = y_ref[...].astype(jnp.float32)
-        xx = jnp.sum(x * x, axis=-1)[:, None]
-        yy = jnp.sum(y * y, axis=-1)[None, :]
-        d2 = xx + yy - 2.0 * jax.lax.dot_general(
-            x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ok = (
-            (d2 <= eps_sq_ref[0])
-            & (xm_ref[...] > 0)[:, None]
-            & (ym_ref[...] > 0)[None, :]
-            & (core_ref[...] > 0)[None, :]
-        )
-        labs = jnp.where(ok, lab_ref[...][None, :], jnp.int32(2**30))
-        o_ref[...] = jnp.minimum(o_ref[...], jnp.min(labs, axis=1))
+    call = _sparse_call(_count_sparse_kernel, rows.shape[0], n, d, bt, 1,
+                        interpret)
+    out = call(rows, cols, flags, _eps_sq(eps), x, x.T, _col(mask),
+               _row(mask))
+    return out.reshape(n)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -299,27 +299,8 @@ def min_label_sweep_sparse(
     """
     n, d = x.shape
     assert n % bt == 0, (n, bt)
-    n_pairs = rows.shape[0]
-    eps_sq = jnp.asarray([jnp.asarray(eps, jnp.float32) ** 2])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1,), lambda p, r, c, f: (0,)),
-            pl.BlockSpec((bt, d), lambda p, r, c, f: (r[p], 0)),
-            pl.BlockSpec((bt, d), lambda p, r, c, f: (c[p], 0)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (r[p],)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (c[p],)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (c[p],)),
-            pl.BlockSpec((bt,), lambda p, r, c, f: (c[p],)),
-        ],
-        out_specs=pl.BlockSpec((bt,), lambda p, r, c, f: (r[p],)),
-    )
-    return pl.pallas_call(
-        _min_label_sparse_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
-    )(rows, cols, flags, eps_sq, x, x, mask.astype(jnp.int32),
-      mask.astype(jnp.int32), labels.astype(jnp.int32),
-      core.astype(jnp.int32))
+    call = _sparse_call(_min_label_sparse_kernel, rows.shape[0], n, d, bt, 3,
+                        interpret)
+    out = call(rows, cols, flags, _eps_sq(eps), x, x.T, _col(mask),
+               _row(mask), _row(labels), _row(core))
+    return out.reshape(n)

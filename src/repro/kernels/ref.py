@@ -14,24 +14,41 @@ Array = jax.Array
 def pairwise_dist_sq(x: Array, y: Array) -> Array:
     """Squared Euclidean distances.  x: (n, d), y: (m, d) -> (n, m).
 
-    Uses the MXU-friendly expansion ||x||^2 + ||y||^2 - 2 x.y^T but computed
-    here in full precision as the semantic reference.
+    Uses the MXU-friendly expansion ||x||^2 + ||y||^2 - 2 x.y^T with the
+    dot at ``Precision.HIGHEST`` (a TPU's default f32 matmul takes one
+    bf16 pass).  For arbitrary d (k-means embeddings); the eps-threshold
+    ops below use the exact difference form instead.
     """
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
     d2 = (
         jnp.sum(x * x, axis=-1)[:, None]
         + jnp.sum(y * y, axis=-1)[None, :]
-        - 2.0 * (x @ y.T)
+        - 2.0 * jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
     )
     return jnp.maximum(d2, 0.0)
+
+
+def diff_d2(x: Array, yt: Array) -> Array:
+    """Squared distances in difference form: x (n, d) rows against yt
+    (d, m) columns -> (n, m), summed over d in index order.
+
+    Only IEEE subtract, multiply and add, so every backend rounds it the
+    same way: the phase-1 kernels evaluate this very expression on their
+    tiles, which is what makes them bit-equal to the oracles below on any
+    device (DESIGN.md §4)."""
+    d2 = None
+    for k in range(x.shape[1]):
+        diff = x[:, k:k + 1].astype(jnp.float32) - yt[k:k + 1, :].astype(jnp.float32)
+        d2 = diff * diff if d2 is None else d2 + diff * diff
+    return d2
 
 
 def neighbor_count(x: Array, mask: Array, eps: float) -> Array:
     """DDC/DBSCAN hot-spot: per-point count of masked points within eps
     (self included).  x: (n, d), mask: (n,) bool -> (n,) int32."""
-    d2 = pairwise_dist_sq(x, x)
-    adj = (d2 <= eps * eps) & mask[None, :] & mask[:, None]
+    d2 = diff_d2(x, x.T)
+    adj = (d2 <= jnp.asarray(eps, jnp.float32) ** 2) & mask[None, :] & mask[:, None]
     return jnp.sum(adj, axis=1).astype(jnp.int32)
 
 
@@ -42,9 +59,7 @@ def contour_min_d2(contours: Array, counts: Array, valid: Array) -> Array:
     contours: (m, v, 2); counts: (m,) valid verts per slot; valid: (m,)
     slot validity.  Returns (m, m) f32 with 1e30 where either slot has no
     valid vertices.  Memory-bounded: one row of clusters at a time against
-    all vertices (the difference form here is the semantic reference; the
-    Pallas kernel uses the centred MXU expansion and must match within
-    tolerance)."""
+    all vertices, in the difference form the Pallas kernel also uses."""
     m, v, _ = contours.shape
     big = jnp.float32(1e30)
     pts = contours.astype(jnp.float32)
@@ -53,7 +68,7 @@ def contour_min_d2(contours: Array, counts: Array, valid: Array) -> Array:
     flat_valid = vert_valid.reshape(m * v)
 
     def row(i):
-        d2 = jnp.sum((pts[i][:, None, :] - flat[None, :, :]) ** 2, axis=-1)
+        d2 = diff_d2(pts[i], flat.T)
         d2 = jnp.where(vert_valid[i][:, None] & flat_valid[None, :], d2, big)
         return jnp.min(d2.reshape(v, m, v), axis=(0, 2))  # (m,)
 
@@ -64,7 +79,7 @@ def min_label_sweep(x: Array, mask: Array, labels: Array, core: Array,
                     eps) -> Array:
     """One DBSCAN min-label sweep: per point, the min label over masked
     core points within eps (2**30 where none)."""
-    d2 = pairwise_dist_sq(x, x)
+    d2 = diff_d2(x, x.T)
     ok = (
         (d2 <= jnp.asarray(eps, jnp.float32) ** 2)
         & mask[:, None] & mask[None, :] & core[None, :]
@@ -107,7 +122,7 @@ def neighbor_count_sparse(x: Array, mask: Array, eps,
     eps_sq = jnp.asarray(eps, jnp.float32) ** 2
 
     def contrib(xt, yt, xm, ym, r, c, valid):
-        d2 = pairwise_dist_sq(xt, yt)
+        d2 = diff_d2(xt, yt.T)
         within = (d2 <= eps_sq) & xm[:, None] & ym[None, :] & valid
         return jnp.sum(within.astype(jnp.int32), axis=1)
 
@@ -126,7 +141,7 @@ def min_label_sweep_sparse(x: Array, mask: Array, labels: Array, core: Array,
     cb = core.reshape(t, bt)
 
     def contrib(xt, yt, xm, ym, r, c, valid):
-        d2 = pairwise_dist_sq(xt, yt)
+        d2 = diff_d2(xt, yt.T)
         ok = ((d2 <= eps_sq) & xm[:, None] & ym[None, :]
               & jnp.take(cb, c, axis=0)[None, :] & valid)
         labs = jnp.where(ok, jnp.take(lb, c, axis=0)[None, :], jnp.int32(2**30))

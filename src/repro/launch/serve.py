@@ -67,6 +67,9 @@ import numpy as np
 
 
 def main(argv=None):
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("lm", "ddc", "track"), default="lm")
     # LM mode

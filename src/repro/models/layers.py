@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.kernels import ops
 from repro.parallel import api as par
 
@@ -598,7 +597,7 @@ def moe_apply(cfg, p: Params, x):
                 parts = jax.tree.map(lambda a_: jax.lax.psum(a_, psum_axes), parts)
             return y, parts
 
-        y, parts = compat.shard_map(
+        y, parts = jax.shard_map(
             island,
             mesh=c.mesh,
             in_specs=(
@@ -626,7 +625,7 @@ def moe_apply(cfg, p: Params, x):
                 parts = jax.tree.map(lambda a_: jax.lax.psum(a_, psum_axes), parts)
             return y, parts
 
-        y, parts = compat.shard_map(
+        y, parts = jax.shard_map(
             island,
             mesh=c.mesh,
             in_specs=(

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 
 def pytree_wire_bytes(tree) -> int:
     """Static wire footprint of a pytree in bytes: sum over leaves of
@@ -157,7 +155,7 @@ def shard_map_all_reduce(grads, mesh, axes=("pod", "data")):
         return (qsum.astype(jnp.float32) * s / n).astype(g.dtype)
 
     def one(g):
-        return compat.shard_map(
+        return jax.shard_map(
             island, mesh=mesh,
             in_specs=P(*[None] * g.ndim), out_specs=P(*[None] * g.ndim),
             check_vma=False,

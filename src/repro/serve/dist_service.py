@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import ddc
 from repro.launch import mesh as mesh_mod
 from repro.parallel import compress
@@ -65,13 +64,10 @@ AXIS = "shards"
 def require_devices(shards: int) -> None:
     """The dist data plane pins one shard per device; fail with the fix
     spelled out instead of an opaque mesh error."""
-    ndev = len(jax.devices())
-    if ndev < shards:
-        raise ValueError(
-            f"backend='dist' pins one shard per device but jax sees "
-            f"{ndev} device(s) for shards={shards}; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={shards} before jax "
-            f"initialises (or lower shards)")
+    shortfall = mesh_mod.device_shortfall(shards)
+    if shortfall:
+        raise ValueError(f"backend='dist' pins one shard per device: "
+                         f"shards={shards} {shortfall}")
 
 
 @functools.lru_cache(maxsize=None)
@@ -87,8 +83,8 @@ def _data_plane(mesh, cfg: ddc.DDCConfig, cap: int, bmax: int, qmax: int):
     empty_cs = ddc.empty_clusterset(cfg)
 
     def smap(f, in_specs, out_specs):
-        return compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def lane_append(pts, mask, chunk, idx, nb):
         wvalid = jnp.arange(chunk.shape[1]) < nb[0]

@@ -78,6 +78,21 @@ class TestDistConfigRules:
         with pytest.raises(ConfigError, match="xla_force_host_platform"):
             DDCConfig(backend="dist", shards=8).validate()
 
+    def test_shortfall_on_a_chip_names_chips_not_the_cpu_flag(self, monkeypatch):
+        # On an accelerator the CPU device-count flag does nothing: the
+        # advice is the number of chips the shards need.
+        import jax
+
+        from repro.launch import mesh as mesh_mod
+
+        class Chip:
+            platform = "tpu"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+        msg = mesh_mod.device_shortfall(4)
+        assert "4 chips" in msg and "xla_force" not in msg
+        assert mesh_mod.device_shortfall(1) is None
+
     def test_rejects_capacity_below_max_batch(self):
         with pytest.raises(ConfigError, match="max_batch"):
             DDCConfig(backend="dist", shards=1, capacity=8,

@@ -93,8 +93,8 @@ class TestContourMinD2:
                                    rtol=1e-4, atol=1e-5)
 
     def test_centred_offset_data(self):
-        """The kernel's MXU expansion must survive a large coordinate
-        offset (the centring step, DESIGN.md §4 item 6)."""
+        """The kernel must survive a large coordinate offset (difference
+        form, DESIGN.md §4 item 6)."""
         m, v = 16, 32
         base = jnp.asarray(RNG.uniform(0, 1, (m, v, 2)), jnp.float32)
         counts = jnp.full((m,), v, jnp.int32)

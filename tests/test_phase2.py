@@ -184,9 +184,8 @@ def _with_axis(fn, cs, cfg, meter):
     """Run a schedule under an abstract 8-lane mesh (shape-only trace)."""
     mesh = _abstract_mesh8()
     from jax.sharding import PartitionSpec as P
-    from repro import compat
 
-    wrapped = compat.shard_map(
+    wrapped = jax.shard_map(
         lambda c: fn(c, cfg, "data", meter),
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), cs),),
@@ -197,5 +196,5 @@ def _with_axis(fn, cs, cfg, meter):
 
 
 def _abstract_mesh8():
-    from repro import compat
-    return compat.abstract_mesh((8,), ("data",))
+    from jax.sharding import AbstractMesh
+    return AbstractMesh((8,), ("data",))

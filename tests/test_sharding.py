@@ -1,16 +1,15 @@
 """Sharding-rule unit tests (AbstractMesh — no devices needed)."""
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat
 from repro.parallel import api as par
 from repro.parallel import sharding as sr
 
 
 def mesh2(multi_pod=False):
     if multi_pod:
-        return compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return compat.abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def ctx(fsdp=False, multi_pod=False):
