@@ -95,6 +95,12 @@ class DBSCANResult(NamedTuple):
     core: jax.Array     # (n,) bool
     n_clusters: jax.Array  # () int32
     n_sweeps: jax.Array  # () int32 — propagation sweeps to convergence
+    # Block-sparse path only (0 / False on the dense path): tile pairs
+    # within eps, tile pairs in all (T²), and whether the sweeps fell
+    # back to the dense kernels.
+    tile_pairs_active: jax.Array  # () int32
+    tile_pairs: jax.Array         # () int32
+    dense_fallback: jax.Array     # () bool
 
 
 def _shortcut(labels: jax.Array, steps: int) -> jax.Array:
@@ -110,7 +116,8 @@ def _shortcut(labels: jax.Array, steps: int) -> jax.Array:
         jumped = jnp.take(l, jnp.where(l < n, l, 0))
         return jnp.minimum(l, jnp.where(l < n, jumped, l))
 
-    return jax.lax.fori_loop(0, steps, body, labels)
+    with jax.named_scope("p1.doubling"):
+        return jax.lax.fori_loop(0, steps, body, labels)
 
 
 def spatial_sort(points: jax.Array, mask: jax.Array, bt: int):
@@ -152,9 +159,10 @@ def _propagate(sweep_fn, init: jax.Array, core: jax.Array, max_iters: int,
             new = _shortcut(new, doubling_steps)
         return new, jnp.any(new != labels), it + 1
 
-    labels, _, n_sweeps = jax.lax.while_loop(
-        cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
-    )
+    with jax.named_scope("p1.propagate"):
+        labels, _, n_sweeps = jax.lax.while_loop(
+            cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
+        )
     return labels, n_sweeps
 
 
@@ -210,7 +218,8 @@ def dbscan(
             dense_fallback_frac=dense_fallback_frac,
         )
 
-    counts = ops.neighbor_count(points, mask, eps)
+    with jax.named_scope("p1.count"):
+        counts = ops.neighbor_count(points, mask, eps)
     core = (counts >= min_pts) & mask
     init = jnp.where(core, jnp.arange(n, dtype=jnp.int32), SENTINEL)
     labels, n_sweeps = _propagate(
@@ -219,7 +228,8 @@ def dbscan(
     )
 
     # Border points: min core-neighbour label (non-core, in-mask).
-    swept = ops.min_label_sweep(points, mask, labels, core, eps)
+    with jax.named_scope("p1.border"):
+        swept = ops.min_label_sweep(points, mask, labels, core, eps)
     labels = jnp.where(core, labels, swept)
     labels = jnp.where(mask & (labels < SENTINEL), labels, SENTINEL)
 
@@ -227,7 +237,9 @@ def dbscan(
     is_root = core & (labels == jnp.arange(n, dtype=jnp.int32))
     n_clusters = jnp.sum(is_root.astype(jnp.int32))
     labels = jnp.where(labels == SENTINEL, NOISE, labels)
-    return DBSCANResult(labels, core, n_clusters, n_sweeps)
+    zero = jnp.asarray(0, jnp.int32)
+    return DBSCANResult(labels, core, n_clusters, n_sweeps, zero, zero,
+                        jnp.asarray(False))
 
 
 def _dbscan_block_sparse(
@@ -245,10 +257,12 @@ def _dbscan_block_sparse(
     sweeps -> canonicalise -> inverse permutation.  Bit-identical to the
     dense path (see DESIGN.md §4 for the argument)."""
     n = points.shape[0]
-    sp, sm, order = spatial_sort(points, mask, bt)
+    with jax.named_scope("p1.sort"):
+        sp, sm, order = spatial_sort(points, mask, bt)
     npad = sp.shape[0]
 
-    pairs = ops.build_tile_pairs(sp, sm, eps, bt=bt)
+    with jax.named_scope("p1.tiles"):
+        pairs = ops.build_tile_pairs(sp, sm, eps, bt=bt)
     use_sparse = pairs.frac <= dense_fallback_frac
 
     def sweep(labels, core):
@@ -259,11 +273,12 @@ def _dbscan_block_sparse(
             labels, core,
         )
 
-    counts = jax.lax.cond(
-        use_sparse,
-        lambda: ops.neighbor_count_sparse(sp, sm, eps, pairs, bt=bt),
-        lambda: ops.neighbor_count(sp, sm, eps),
-    )
+    with jax.named_scope("p1.count"):
+        counts = jax.lax.cond(
+            use_sparse,
+            lambda: ops.neighbor_count_sparse(sp, sm, eps, pairs, bt=bt),
+            lambda: ops.neighbor_count(sp, sm, eps),
+        )
     core = (counts >= min_pts) & sm
     init = jnp.where(core, jnp.arange(npad, dtype=jnp.int32), SENTINEL)
     labels, n_sweeps = _propagate(
@@ -281,7 +296,8 @@ def _dbscan_block_sparse(
     canon = jnp.where(core, jnp.take(min_orig, root), SENTINEL)
 
     # Border points: min canonical core-neighbour label.
-    swept = sweep(canon, core)
+    with jax.named_scope("p1.border"):
+        swept = sweep(canon, core)
     labels_s = jnp.where(core, canon, swept)
     labels_s = jnp.where(sm & (labels_s < SENTINEL), labels_s, SENTINEL)
 
@@ -292,7 +308,9 @@ def _dbscan_block_sparse(
     is_root = core_o & (labels == jnp.arange(n, dtype=jnp.int32))
     n_clusters = jnp.sum(is_root.astype(jnp.int32))
     labels = jnp.where(labels == SENTINEL, NOISE, labels)
-    return DBSCANResult(labels, core_o, n_clusters, n_sweeps)
+    return DBSCANResult(labels, core_o, n_clusters, n_sweeps, pairs.n_active,
+                        jnp.asarray(pairs.rows.shape[0], jnp.int32),
+                        ~use_sparse)
 
 
 def relabel_dense(labels: jax.Array, max_clusters: int) -> jax.Array:

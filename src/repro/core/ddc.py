@@ -114,15 +114,22 @@ def empty_clusterset(cfg: DDCConfig) -> ClusterSet:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def local_phase(
-    points: jax.Array, mask: jax.Array, cfg: DDCConfig, key: jax.Array | None = None
-) -> Tuple[jax.Array, ClusterSet]:
-    """Cluster a shard's points and reduce to contours.
+class Phase1Stats(NamedTuple):
+    """What one shard's phase 1 did (host-side counters, not wire data)."""
 
-    Returns (dense local labels (n,), ClusterSet).  Zero communication.
-    """
-    n = points.shape[0]
+    sweeps: jax.Array             # () i32 — label sweeps to convergence
+    tile_pairs_active: jax.Array  # () i32 — tile pairs within eps
+    tile_pairs: jax.Array         # () i32 — tile pairs in all (T²)
+    dense_fallback: jax.Array     # () bool — sweeps ran the dense kernels
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def local_phase_stats(
+    points: jax.Array, mask: jax.Array, cfg: DDCConfig, key: jax.Array | None = None
+) -> Tuple[jax.Array, ClusterSet, Phase1Stats]:
+    """``local_phase`` plus its ``Phase1Stats``, from the same program.
+    The tile-pair counts and the fallback flag are 0 / False off the
+    block-sparse path, and every count is 0 for K-Means."""
     c_budget = cfg.max_clusters
     if cfg.local_algo == "dbscan":
         res = dbscan_mod.dbscan(
@@ -131,28 +138,33 @@ def local_phase(
         )
         dense = dbscan_mod.relabel_dense(res.labels, c_budget)
         n_clusters = res.n_clusters
+        stats = Phase1Stats(res.n_sweeps, res.tile_pairs_active,
+                            res.tile_pairs, res.dense_fallback)
     elif cfg.local_algo == "kmeans":
         if key is None:
             key = jax.random.PRNGKey(0)
         km = kmeans.kmeans(key, points, mask, min(cfg.kmeans_k, c_budget))
         dense = km.labels
         n_clusters = jnp.asarray(min(cfg.kmeans_k, c_budget), jnp.int32)
+        zero = jnp.asarray(0, jnp.int32)
+        stats = Phase1Stats(zero, zero, zero, jnp.asarray(False))
     else:  # pragma: no cover
         raise ValueError(cfg.local_algo)
 
-    sizes = jnp.zeros((c_budget,), jnp.int32).at[jnp.clip(dense, 0)].add(
-        (dense >= 0).astype(jnp.int32), mode="drop"
-    )
-    valid = sizes > 0
-
-    def one_contour(cid):
-        m = mask & (dense == cid)
-        pts, cnt = geometry.extract_contour(
-            points, m, cfg.bounds, cfg.grid, cfg.max_verts
+    with jax.named_scope("p1.contours"):
+        sizes = jnp.zeros((c_budget,), jnp.int32).at[jnp.clip(dense, 0)].add(
+            (dense >= 0).astype(jnp.int32), mode="drop"
         )
-        return pts, cnt
+        valid = sizes > 0
 
-    contours, counts = jax.vmap(one_contour)(jnp.arange(c_budget))
+        def one_contour(cid):
+            m = mask & (dense == cid)
+            pts, cnt = geometry.extract_contour(
+                points, m, cfg.bounds, cfg.grid, cfg.max_verts
+            )
+            return pts, cnt
+
+        contours, counts = jax.vmap(one_contour)(jnp.arange(c_budget))
     cs = ClusterSet(
         contours=contours,
         counts=jnp.where(valid, counts, 0),
@@ -160,6 +172,18 @@ def local_phase(
         valid=valid,
         overflow=n_clusters > c_budget,
     )
+    return dense, cs, stats
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def local_phase(
+    points: jax.Array, mask: jax.Array, cfg: DDCConfig, key: jax.Array | None = None
+) -> Tuple[jax.Array, ClusterSet]:
+    """Cluster a shard's points and reduce to contours.
+
+    Returns (dense local labels (n,), ClusterSet).  Zero communication.
+    """
+    dense, cs, _ = local_phase_stats(points, mask, cfg, key)
     return dense, cs
 
 
@@ -261,7 +285,8 @@ def contour_pair_d2_exact(batch: ClusterSet, cfg: DDCConfig) -> jax.Array:
     contours = batch.contours.reshape(m, v, 2)
     counts = batch.counts.reshape(m)
     valid = batch.valid.reshape(m)
-    return cross_min_d2(contours, counts, valid, contours, counts, valid)
+    with jax.named_scope("p2.pair_d2"):
+        return cross_min_d2(contours, counts, valid, contours, counts, valid)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0,))
@@ -283,7 +308,8 @@ def update_pair_d2(pair_d2: jax.Array, batch: ClusterSet, shard,
     bc = jax.lax.dynamic_slice(contours, (row0, 0, 0), (c, v, 2))
     bcnt = jax.lax.dynamic_slice(counts, (row0,), (c,))
     bval = jax.lax.dynamic_slice(valid, (row0,), (c,))
-    rows = cross_min_d2(bc, bcnt, bval, contours, counts, valid)   # (C, M)
+    with jax.named_scope("p2.pair_d2"):
+        rows = cross_min_d2(bc, bcnt, bval, contours, counts, valid)  # (C, M)
     pair_d2 = jax.lax.dynamic_update_slice(pair_d2, rows, (row0, 0))
     return jax.lax.dynamic_update_slice(pair_d2, rows.T, (0, row0))
 
@@ -311,8 +337,9 @@ def update_pair_d2_many(pair_d2: jax.Array, batch: ClusterSet, shards,
     valid = batch.valid.reshape(m)
     rows_idx = (shards[:, None] * c
                 + jnp.arange(c, dtype=jnp.int32)[None, :]).reshape(-1)
-    rows = cross_min_d2(contours[rows_idx], counts[rows_idx],
-                        valid[rows_idx], contours, counts, valid)  # (mC, M)
+    with jax.named_scope("p2.pair_d2"):
+        rows = cross_min_d2(contours[rows_idx], counts[rows_idx],
+                            valid[rows_idx], contours, counts, valid)  # (mC, M)
     pair_d2 = pair_d2.at[rows_idx].set(rows)
     return pair_d2.at[:, rows_idx].set(rows.T)
 
@@ -348,7 +375,8 @@ def merge_from_d2(batch: ClusterSet, pair_d2: jax.Array,
     overlap = (pair_d2 <= r * r) & valid[:, None] & valid[None, :]
     overlap = overlap | (jnp.eye(m, dtype=bool) & valid[:, None])
 
-    comp = _components(overlap, valid)                         # (M,)
+    with jax.named_scope("p2.closure"):
+        comp = _components(overlap, valid)                     # (M,)
     roots = valid & (comp == jnp.arange(m, dtype=jnp.int32))
     comp_safe = jnp.clip(comp, 0, m - 1)
     comp_size = jnp.zeros((m,), jnp.int32).at[comp_safe].add(

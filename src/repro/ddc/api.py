@@ -31,6 +31,7 @@ import zipfile
 
 import numpy as np
 
+from repro import obs
 from repro.core import ddc as core_ddc
 from repro.ddc import backends as backends_mod
 from repro.ddc.config import DDCConfig
@@ -67,7 +68,8 @@ class DDC:
         it whenever later ``partial_fit``/``expire`` calls use wall-clock
         timestamps — the default stamp is the ingest sequence number,
         which any wall-clock ``expire`` cutoff would treat as ancient."""
-        self.backend.fit(points, t=t)
+        with obs.span("ddc.fit", backend=self.config.backend, n=len(points)):
+            self.backend.fit(points, t=t)
         return self
 
     def partial_fit(self, shard: int, batch: np.ndarray,

@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import ddc
 from repro.serve import faults as faults_mod
 from repro.serve import hierarchy
@@ -107,6 +108,11 @@ class StreamConfig:
     track_history: int = 16         # per-track motion-history ring length
     match_min_overlap: float = 0.0  # tighten the match gate, in [0, 1)
     ddc: ddc.DDCConfig = dataclasses.field(default_factory=ddc.DDCConfig)
+
+
+# Phase-1 counters of ``ServiceCounters``, kept per service.
+PHASE1_COUNTERS = ("phase1_runs", "phase1_sweeps", "phase1_tile_pairs_active",
+                   "phase1_tile_pairs", "phase1_dense_fallbacks")
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +164,8 @@ def _global_labels(dense, mask, maps):
     """(K, cap) dense local labels + (K, C) slot maps -> global labels."""
     def one(d, m, mp):
         return jnp.where(m & (d >= 0), mp[jnp.clip(d, 0)], -1)
-    return jax.vmap(one)(dense, mask, maps)
+    with jax.named_scope("p2.labels"):
+        return jax.vmap(one)(dense, mask, maps)
 
 
 @jax.jit
@@ -176,16 +183,14 @@ def _query_labels(q, qn, pts, mask, glabels, eps):
     return jnp.where(jnp.arange(q.shape[0]) < qn, lab, -1)
 
 
-def _cs_to_host(cs: ddc.ClusterSet) -> dict:
+def _cs_to_host(cs: ddc.ClusterSet, stats: ddc.Phase1Stats | None = None
+                ) -> Tuple[dict, Optional[ddc.Phase1Stats]]:
     """One shard's delta as the host-side wire payload the validation
-    gate (and the fault seam) sees: plain numpy views of the leaves."""
-    return {
-        "contours": np.asarray(cs.contours),
-        "counts": np.asarray(cs.counts),
-        "sizes": np.asarray(cs.sizes),
-        "valid": np.asarray(cs.valid),
-        "overflow": np.asarray(cs.overflow),
-    }
+    gate (and the fault seam) sees: plain numpy views of the leaves.
+    The shard's phase-1 ``stats``, if any, come back in the same host
+    copy: returns (payload, stats as numpy scalars or None)."""
+    host_cs, host_stats = jax.device_get((cs, stats))
+    return host_cs._asdict(), host_stats
 
 
 def _cs_from_host(payload: dict) -> ddc.ClusterSet:
@@ -285,6 +290,7 @@ class ShardControlPlane:
         self.retries = 0                # delta re-deliveries (monotonic)
         self.quarantine_events = 0      # shards ever quarantined (monotonic)
         self.fenced_deltas = 0          # duplicates the epoch fence dropped
+        self.phase1_counts = dict.fromkeys(PHASE1_COUNTERS, 0)
         self.degraded_queries = 0       # queries routed around quarantine
         self.last_query_degraded = False
         self._route_degraded = False
@@ -353,41 +359,42 @@ class ShardControlPlane:
         cap, bmax = self.scfg.capacity, self.scfg.max_batch
         pts = np.asarray(points, np.float32).reshape(-1, 2)
         n = len(pts)
-        if t is None:
-            ts = np.arange(self._next_seq, self._next_seq + n, dtype=np.float64)
-        else:
-            ts = np.broadcast_to(np.asarray(t, np.float64), (n,))
-        for off in range(0, n, bmax):
-            chunk = pts[off:off + bmax]
-            nb = len(chunk)
-            idx = self._write_slots(shard, nb)
-            pad_idx = idx
-            if nb < bmax:
-                chunk = np.pad(chunk, ((0, bmax - nb), (0, 0)))
-                pad_idx = np.pad(idx, (0, bmax - nb))
-            seqs = np.arange(self._next_seq + off, self._next_seq + off + nb)
-            # Write-ahead: journal the decision before the device write,
-            # so a lane lost mid-append is still recoverable.
-            self._journal.record_ingest(shard, idx, chunk[:nb],
-                                        ts[off:off + nb], seqs)
-            if shard not in self._quarantined:
-                self._append_chunk(shard, chunk, pad_idx, nb)
-            self._live[shard][idx] = True
-            self._hpts[shard][idx] = chunk[:nb]
-            self._ts[shard][idx] = ts[off:off + nb]
-            self._seq[shard][idx] = seqs
-            self._head[shard] = int(idx[-1] + 1) % cap
-            self._count[shard] = int(self._live[shard].sum())
-        if self._journal.needs_compaction(shard):
-            self._journal.compact(shard, self._hpts[shard],
-                                  self._live[shard], self._ts[shard],
-                                  self._seq[shard])
-        self._next_seq += n
-        if n and shard not in self._quarantined:
-            self._dirty.add(shard)
-        if n:
-            self._bbox[shard] = None
-            self._invalidate_reads()
+        with obs.span("ddc.ingest", shard=shard, n=n):
+            if t is None:
+                ts = np.arange(self._next_seq, self._next_seq + n, dtype=np.float64)
+            else:
+                ts = np.broadcast_to(np.asarray(t, np.float64), (n,))
+            for off in range(0, n, bmax):
+                chunk = pts[off:off + bmax]
+                nb = len(chunk)
+                idx = self._write_slots(shard, nb)
+                pad_idx = idx
+                if nb < bmax:
+                    chunk = np.pad(chunk, ((0, bmax - nb), (0, 0)))
+                    pad_idx = np.pad(idx, (0, bmax - nb))
+                seqs = np.arange(self._next_seq + off, self._next_seq + off + nb)
+                # Write-ahead: journal the decision before the device write,
+                # so a lane lost mid-append is still recoverable.
+                self._journal.record_ingest(shard, idx, chunk[:nb],
+                                            ts[off:off + nb], seqs)
+                if shard not in self._quarantined:
+                    self._append_chunk(shard, chunk, pad_idx, nb)
+                self._live[shard][idx] = True
+                self._hpts[shard][idx] = chunk[:nb]
+                self._ts[shard][idx] = ts[off:off + nb]
+                self._seq[shard][idx] = seqs
+                self._head[shard] = int(idx[-1] + 1) % cap
+                self._count[shard] = int(self._live[shard].sum())
+            if self._journal.needs_compaction(shard):
+                self._journal.compact(shard, self._hpts[shard],
+                                      self._live[shard], self._ts[shard],
+                                      self._seq[shard])
+            self._next_seq += n
+            if n and shard not in self._quarantined:
+                self._dirty.add(shard)
+            if n:
+                self._bbox[shard] = None
+                self._invalidate_reads()
 
     def _write_slots(self, shard: int, nb: int) -> np.ndarray:
         """Pick the ``nb`` slots the next append chunk writes: dead slots
@@ -742,7 +749,59 @@ class ShardControlPlane:
 
     def refresh(self, mode: str | None = None, force: bool = False,
                 track: bool | None = None):
+        """Re-cluster dirty shards and fold them into the global state.
+
+        ``mode`` overrides the configured merge mode for this call;
+        ``force`` recomputes even with no dirty shards (the full-remerge
+        baseline the benchmark times); ``track`` is the per-call
+        tracking override (``_track_update``).  Returns the global
+        ClusterSet.  The data plane runs phase 1 and the delta exchange
+        (``_refresh_shards``) and relabels its points against the new
+        slot maps (``_relabel``); the merge and the publish are shared.
+        """
+        mode = mode or self.scfg.merge_mode
+        dirty = sorted(self._dirty - self._quarantined.keys())
+        if not dirty and self._global is not None and not force:
+            return self._global
+        with obs.span("ddc.refresh", dirty=len(dirty), mode=mode):
+            staged, up_bytes = self._refresh_shards(dirty, mode)
+            # Ends in the publish's host read of the merged set, so the
+            # span holds the merge's device work.
+            with obs.span("ddc.aggregate", mode=mode, staged=len(staged)):
+                self._merge_and_meter(staged, mode, up_bytes)
+                self._relabel()
+                self._dirty -= set(staged)
+                self._track_update(track)
+                self.refreshes += 1
+                self._publish_snapshot()
+        return self._global
+
+    def _refresh_shards(self, dirty: list, mode: str
+                        ) -> Tuple[list, Optional[int]]:
+        """Data-plane hook: phase 1 on the ``dirty`` shards and their
+        delta exchange (``_exchange_deltas``).  Returns (staged shards,
+        measured up-leg bytes or None for the metered model)."""
         raise NotImplementedError
+
+    def _relabel(self) -> None:
+        """Data-plane hook: send the slot maps down (metered) and
+        recompute the global labels of every shard's points."""
+        raise NotImplementedError
+
+    def _count_phase1(self, st: ddc.Phase1Stats) -> dict:
+        """Fold one phase-1 run's stats into the counters; returns them
+        as span attributes."""
+        attrs = {"sweeps": int(st.sweeps),
+                 "tile_pairs_active": int(st.tile_pairs_active),
+                 "tile_pairs": int(st.tile_pairs),
+                 "dense_fallback": bool(st.dense_fallback)}
+        c = self.phase1_counts
+        c["phase1_runs"] += 1
+        c["phase1_sweeps"] += attrs["sweeps"]
+        c["phase1_tile_pairs_active"] += attrs["tile_pairs_active"]
+        c["phase1_tile_pairs"] += attrs["tile_pairs"]
+        c["phase1_dense_fallbacks"] += int(attrs["dense_fallback"])
+        return attrs
 
     # -- cluster tracking (DESIGN.md §14) -----------------------------------
 
@@ -905,6 +964,7 @@ class ShardControlPlane:
             retries=self.retries,
             quarantine_events=self.quarantine_events,
             fenced_deltas=self.fenced_deltas,
+            **self.phase1_counts,
             journal_entries=self._journal.entries_total,
         )
         oldest_ts, newest_ts = self.window_ts()
@@ -986,6 +1046,7 @@ class ShardControlPlane:
             "retries": self.retries,
             "quarantine_events": self.quarantine_events,
             "fenced_deltas": self.fenced_deltas,
+            **self.phase1_counts,
             "degraded_queries": self.degraded_queries,
             "journal_entries": self._journal.entries_total,
             "snapshot_version": self._snapshot_version,
@@ -1028,6 +1089,8 @@ class ShardControlPlane:
         self.retries = int(manifest.get("retries", 0))
         self.quarantine_events = int(manifest.get("quarantine_events", 0))
         self.fenced_deltas = int(manifest.get("fenced_deltas", 0))
+        self.phase1_counts = {c: int(manifest.get(c, 0))
+                              for c in PHASE1_COUNTERS}
         self.degraded_queries = int(manifest.get("degraded_queries", 0))
         # Version monotonicity survives save/load: the restore publish
         # continues from the saved counter, never rewinds it.
@@ -1098,15 +1161,17 @@ class ShardControlPlane:
         """
         if self._dirty or self._global is None:
             self.refresh()
-        pts, mask, glab = self._live_buffers()
-        pts_rows, parts, labels = [], [], []
-        base = 0
-        for s in range(self.scfg.shards):
-            msk = mask[s]
-            pts_rows.append(pts[s][msk])
-            labels.append(glab[s][msk])
-            parts.append(np.arange(base, base + int(msk.sum())))
-            base += int(msk.sum())
+        with obs.span("ddc.live") as attrs:
+            pts, mask, glab = self._live_buffers()
+            pts_rows, parts, labels = [], [], []
+            base = 0
+            for s in range(self.scfg.shards):
+                msk = mask[s]
+                pts_rows.append(pts[s][msk])
+                labels.append(glab[s][msk])
+                parts.append(np.arange(base, base + int(msk.sum())))
+                base += int(msk.sum())
+            attrs["n_live"] = base
         return (np.concatenate(pts_rows) if base else np.zeros((0, 2), np.float32),
                 parts,
                 np.concatenate(labels) if base else np.zeros((0,), np.int32))
@@ -1196,43 +1261,31 @@ class ClusterService(ShardControlPlane):
 
     # -- refresh (phase 1 on dirty shards + delta/full merge) --------------
 
-    def refresh(self, mode: str | None = None, force: bool = False,
-                track: bool | None = None):
-        """Re-cluster dirty shards and fold them into the global state.
-
-        ``mode`` overrides the configured merge mode for this call;
-        ``force`` recomputes even with no dirty shards (the full-remerge
-        baseline the benchmark times); ``track`` is the per-call
-        tracking override (``_track_update``).  Returns the global
-        ClusterSet.
-        """
-        mode = mode or self.scfg.merge_mode
+    def _refresh_shards(self, dirty, mode):
         cfg = self.cfg
-        dirty = sorted(self._dirty - self._quarantined.keys())
-        if not dirty and self._global is not None and not force:
-            return self._global
 
         def produce(i, attempt):
-            if self._count[i] == 0:
-                # Emptied shard: the cached all-invalid ClusterSet, no
-                # phase-1 work (extends the PR 2 empty-shard fix).
-                cs = ddc.empty_clusterset(cfg)
-                dense = jnp.full((self.scfg.capacity,), -1, jnp.int32)
-            else:
-                dense, cs = ddc.local_phase(self._pts[i], self._mask[i], cfg)
-            self._dense = _set_row(self._dense, dense, i)
-            return _cs_to_host(cs), cs
+            with obs.span("ddc.phase1", shard=i, attempt=attempt) as attrs:
+                if self._count[i] == 0:
+                    # Emptied shard: the cached all-invalid ClusterSet, no
+                    # phase-1 work.
+                    cs, st = ddc.empty_clusterset(cfg), None
+                    dense = jnp.full((self.scfg.capacity,), -1, jnp.int32)
+                else:
+                    dense, cs, st = ddc.local_phase_stats(
+                        self._pts[i], self._mask[i], cfg)
+                self._dense = _set_row(self._dense, dense, i)
+                payload, st = _cs_to_host(cs, st)
+                if st is not None:
+                    attrs.update(self._count_phase1(st))
+            return payload, cs
 
-        staged = self._exchange_deltas(dirty, produce)
-        self._merge_and_meter(staged, mode)
+        return self._exchange_deltas(dirty, produce), None
+
+    def _relabel(self) -> None:
         self._meter_maps_down()
         self._glabels = _global_labels(
             self._dense, jnp.stack(self._mask), self._maps)
-        self._dirty -= set(staged)
-        self._track_update(track)
-        self.refreshes += 1
-        self._publish_snapshot()
-        return self._global
 
     # -- read path ---------------------------------------------------------
 

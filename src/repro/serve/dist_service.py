@@ -255,19 +255,13 @@ class DistClusterService(ShardControlPlane):
 
     # -- refresh (lane-local phase 1 + delta exchange + merge) --------------
 
-    def refresh(self, mode: str | None = None, force: bool = False,
-                track: bool | None = None):
-        """Re-cluster dirty lanes on their own devices, exchange ONLY
-        their delta ClusterSets across the axis, and re-close the cached
-        merge.  Bit-identical to ``ClusterService.refresh`` on the same
-        call sequence (and to a from-scratch re-merge), including the
-        tracking fold (``track`` as in ``_track_update``)."""
-        mode = mode or self.scfg.merge_mode
+    def _refresh_shards(self, dirty, mode):
+        """Re-cluster dirty lanes on their own devices and exchange ONLY
+        their delta ClusterSets across the axis; the shared ``refresh``
+        then re-closes the cached merge.  Bit-identical to the ``stream``
+        engine on the same call sequence (and to a from-scratch
+        re-merge), including the tracking fold."""
         k = self.scfg.shards
-        dirty = sorted(self._dirty - self._quarantined.keys())
-        if not dirty and self._global is not None and not force:
-            return self._global
-
         if dirty:
             flags = np.zeros((k,), bool)
             flags[dirty] = True
@@ -346,18 +340,15 @@ class DistClusterService(ShardControlPlane):
                     self._local[i] = cs
                     self._batch = _set_row(self._batch, cs, i)
 
-        self._merge_and_meter(staged, mode, up_bytes=up_bytes[0])
+        return staged, up_bytes[0]
+
+    def _relabel(self) -> None:
         # Map rows back down, lane-local relabel; again metered from the
         # array actually pushed.
         maps_np = np.asarray(self._maps, np.int32)
         self._meter_maps_down(maps_np.nbytes)
         maps_dev = jax.device_put(maps_np, self._sh2)
         self._glabels = self._fns["labels"](self._dense, self._mask, maps_dev)
-        self._dirty -= set(staged)
-        self._track_update(track)
-        self.refreshes += 1
-        self._publish_snapshot()
-        return self._global
 
     # -- read path ----------------------------------------------------------
 

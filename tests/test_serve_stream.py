@@ -233,3 +233,96 @@ class TestQuery:
         before = svc.refreshes
         svc.query(pts[:8])
         assert svc.refreshes == before + 1
+
+
+class TestFitSpansAndPhase1Counters:
+    """A stream fit through the facade records its layer spans
+    (``repro.obs``) and folds each shard's phase-1 stats into
+    ``ServiceStats``; block-sparse is forced so the tile-pair counts are
+    not 0."""
+
+    K = 4
+
+    def fit(self):
+        from repro import obs
+        from repro.ddc import DDC, DDCConfig
+
+        spec = spatial.PHASE2_LAYOUTS["rings"]
+        pts = spec["make"](N)
+        cfg = DDCConfig(eps=spec["eps"], min_pts=spec["min_pts"],
+                        grid=spec["grid"], max_clusters=spec["max_clusters"],
+                        max_verts=spec["max_verts"], block_sparse="always",
+                        block_tile=128, backend="stream", shards=self.K,
+                        max_batch=256)
+        obs.clear()
+        model = DDC(cfg).fit(pts)
+        labels = model.labels_
+        return model, spec, labels, obs.spans()
+
+    def test_one_phase1_span_per_dirty_shard(self):
+        model, spec, labels, spans = self.fit()
+        by = lambda name: [s for s in spans if s.name == name]  # noqa: E731
+        (fit,) = by("ddc.fit")
+        (refresh,) = by("ddc.refresh")
+        (agg,) = by("ddc.aggregate")
+        (live,) = by("ddc.live")
+        p1 = by("ddc.phase1")
+        assert sorted(s.attrs["shard"] for s in p1) == list(range(self.K))
+        assert all(s.parent_id == refresh.span_id for s in p1)
+        assert agg.parent_id == refresh.span_id
+        assert refresh.parent_id == fit.span_id
+        ingest = by("ddc.ingest")
+        assert len(ingest) == N // 256
+        assert sum(s.attrs["n"] for s in ingest) == N
+        assert all(s.parent_id == fit.span_id for s in ingest)
+        assert {s.trace_id for s in [refresh, agg, *p1, *ingest]} \
+            == {fit.span_id}
+        assert live.attrs["n_live"] == N and live.trace_id != fit.trace_id
+        assert fit.start <= refresh.start and refresh.end <= fit.end
+        assert refresh.attrs == {"dirty": self.K, "mode": "delta"}
+        assert agg.attrs == {"mode": "delta", "staged": self.K}
+        pts, parts, _ = model.service.live()
+        host, _, _ = ddc.ddc_host(pts, len(parts), spec["eps"],
+                                  spec["min_pts"], partition=parts,
+                                  contour="grid")
+        assert ddc.same_clustering(labels, host)
+
+    def test_counters_equal_the_spans_and_survive_restore(self, tmp_path):
+        from repro.ddc import DDC
+        from repro.serve.cluster_service import PHASE1_COUNTERS
+
+        model, _, labels, spans = self.fit()
+        p1 = [s for s in spans if s.name == "ddc.phase1"]
+        c = model.stats().counters
+        assert c.phase1_runs == self.K
+        assert c.phase1_sweeps == sum(s.attrs["sweeps"] for s in p1) > 0
+        tiles = (N // self.K // 128) ** 2
+        assert c.phase1_tile_pairs == self.K * tiles \
+            == sum(s.attrs["tile_pairs"] for s in p1)
+        assert 0 < c.phase1_tile_pairs_active <= c.phase1_tile_pairs
+        assert c.phase1_tile_pairs_active \
+            == sum(s.attrs["tile_pairs_active"] for s in p1)
+        assert c.phase1_dense_fallbacks \
+            == sum(int(s.attrs["dense_fallback"]) for s in p1)
+        d = model.stats().as_dict()
+        assert all(d[k] == getattr(c, k) for k in PHASE1_COUNTERS)
+        back = DDC.load(model.save(str(tmp_path / "snap")))
+        c2 = back.stats().counters
+        assert all(getattr(c2, k) == getattr(c, k) for k in PHASE1_COUNTERS)
+        np.testing.assert_array_equal(back.labels_, labels)
+
+    def test_stats_entry_matches_local_phase_bit_for_bit(self):
+        """The stream engine's phase-1 entry gives the same labels and
+        ClusterSet as ``local_phase`` for every shard."""
+        model, _, _, _ = self.fit()
+        svc = model.service
+        cfg = svc.cfg
+        for i in range(self.K):
+            dense, cs = ddc.local_phase(svc._pts[i], svc._mask[i], cfg)
+            dense2, cs2, st = ddc.local_phase_stats(
+                svc._pts[i], svc._mask[i], cfg)
+            np.testing.assert_array_equal(dense, dense2)
+            for a, b in zip(cs, cs2):
+                np.testing.assert_array_equal(a, b)
+            np.testing.assert_array_equal(np.asarray(svc._dense)[i], dense)
+            assert int(st.sweeps) >= 1
