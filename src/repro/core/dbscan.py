@@ -16,10 +16,10 @@ spatial data (DESIGN.md §4–§5):
   active fraction is high).  Labels come back in caller order, bit-exact
   with the dense path.
 * **Pointer doubling** (``pointer_doubling``): each sweep is followed by
-  ``labels <- min(labels, labels[labels])`` shortcut steps, collapsing
-  label-chase chains so convergence needs O(log n) sweeps instead of
-  O(core-graph diameter) — a worm-shaped cluster needs tens, not
-  hundreds, of O(n²)-cost sweeps.
+  ``labels <- min(labels, labels[labels])`` shortcut steps, run until a
+  step changes nothing, collapsing label-chase chains so convergence
+  needs O(log n) sweeps instead of O(core-graph diameter) — a
+  worm-shaped cluster needs tens, not hundreds, of O(n²)-cost sweeps.
 
 Semantics (both): a point is *core* iff its ε-neighbourhood (self
 included) has >= min_pts points.  Core points within ε of each other share
@@ -95,6 +95,7 @@ class DBSCANResult(NamedTuple):
     core: jax.Array     # (n,) bool
     n_clusters: jax.Array  # () int32
     n_sweeps: jax.Array  # () int32 — propagation sweeps to convergence
+    n_doubling_steps: jax.Array  # () int32 — shortcut gathers run, all sweeps
     # Block-sparse path only (0 / False on the dense path): tile pairs
     # within eps, tile pairs in all (T²), and whether the sweeps fell
     # back to the dense kernels.
@@ -103,21 +104,34 @@ class DBSCANResult(NamedTuple):
     dense_fallback: jax.Array     # () bool
 
 
-def _shortcut(labels: jax.Array, steps: int) -> jax.Array:
-    """Pointer-doubling: ``labels <- min(labels, labels[labels])``, ``steps``
-    times.  Valid because for core i, labels[i] is always the index of a
-    core point in the same cluster (so the jump stays in-cluster and is
-    monotone non-increasing); SENTINEL entries (non-core / padding, all
-    >= n) never jump.  ``steps`` = ceil(log2 n) fully compresses any
-    label chain a sweep can produce."""
+def _shortcut(labels: jax.Array, steps: int, changed: jax.Array):
+    """Pointer doubling: ``labels <- min(labels, labels[labels])`` until a
+    step changes nothing, at most ``steps`` times.  Valid because for core
+    i, labels[i] is always the index of a core point in the same cluster
+    (so the jump stays in-cluster and is monotone non-increasing);
+    SENTINEL entries (non-core / padding, all >= n) never jump.  A step is
+    a function of the labels alone, so once one changes nothing every
+    later one would too: stopping there gives the labels the full
+    ``steps`` would.  ``steps`` = ceil(log2 n) is only a cap: each step
+    halves every label chain, and none is longer than n.  ``changed``
+    False means ``labels`` are already a fixed point and no step runs.
+    Returns (labels, steps run)."""
     n = labels.shape[0]
 
-    def body(_, l):
+    def cond(state):
+        _, changed, k = state
+        return changed & (k < steps)
+
+    def body(state):
+        l, _, k = state
         jumped = jnp.take(l, jnp.where(l < n, l, 0))
-        return jnp.minimum(l, jnp.where(l < n, jumped, l))
+        new = jnp.minimum(l, jnp.where(l < n, jumped, l))
+        return new, jnp.any(new != l), k + 1
 
     with jax.named_scope("p1.doubling"):
-        return jax.lax.fori_loop(0, steps, body, labels)
+        labels, _, k = jax.lax.while_loop(
+            cond, body, (labels, changed, jnp.asarray(0, jnp.int32)))
+    return labels, k
 
 
 def spatial_sort(points: jax.Array, mask: jax.Array, bt: int):
@@ -145,25 +159,33 @@ def spatial_sort(points: jax.Array, mask: jax.Array, bt: int):
 def _propagate(sweep_fn, init: jax.Array, core: jax.Array, max_iters: int,
                doubling_steps: int):
     """Iterate min-label sweeps (+ optional pointer doubling) to fixed
-    point.  Returns (labels, n_sweeps)."""
+    point.  Returns (labels, n_sweeps, doubling steps run).
+
+    The doubling after a sweep that changed nothing is skipped: the
+    previous doubling ran to its fixed point (``init`` is one), so the
+    labels already are one.  Doubling only lowers labels, so the sweep's
+    own change decides whether the iteration changed anything."""
+    zero = jnp.asarray(0, jnp.int32)
 
     def cond(state):
-        _, changed, it = state
+        _, changed, it, _ = state
         return changed & (it < max_iters)
 
     def body(state):
-        labels, _, it = state
+        labels, _, it, n_steps = state
         swept = sweep_fn(labels)
         new = jnp.where(core, jnp.minimum(labels, swept), labels)
+        changed = jnp.any(new != labels)
         if doubling_steps:
-            new = _shortcut(new, doubling_steps)
-        return new, jnp.any(new != labels), it + 1
+            new, k = _shortcut(new, doubling_steps, changed)
+            n_steps = n_steps + k
+        return new, changed, it + 1, n_steps
 
     with jax.named_scope("p1.propagate"):
-        labels, _, n_sweeps = jax.lax.while_loop(
-            cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32))
+        labels, _, n_sweeps, n_steps = jax.lax.while_loop(
+            cond, body, (init, jnp.asarray(True), zero, zero)
         )
-    return labels, n_sweeps
+    return labels, n_sweeps, n_steps
 
 
 @functools.partial(
@@ -222,7 +244,7 @@ def dbscan(
         counts = ops.neighbor_count(points, mask, eps)
     core = (counts >= min_pts) & mask
     init = jnp.where(core, jnp.arange(n, dtype=jnp.int32), SENTINEL)
-    labels, n_sweeps = _propagate(
+    labels, n_sweeps, n_steps = _propagate(
         lambda l: ops.min_label_sweep(points, mask, l, core, eps),
         init, core, max_iters, doubling_steps,
     )
@@ -238,8 +260,8 @@ def dbscan(
     n_clusters = jnp.sum(is_root.astype(jnp.int32))
     labels = jnp.where(labels == SENTINEL, NOISE, labels)
     zero = jnp.asarray(0, jnp.int32)
-    return DBSCANResult(labels, core, n_clusters, n_sweeps, zero, zero,
-                        jnp.asarray(False))
+    return DBSCANResult(labels, core, n_clusters, n_sweeps, n_steps, zero,
+                        zero, jnp.asarray(False))
 
 
 def _dbscan_block_sparse(
@@ -281,7 +303,7 @@ def _dbscan_block_sparse(
         )
     core = (counts >= min_pts) & sm
     init = jnp.where(core, jnp.arange(npad, dtype=jnp.int32), SENTINEL)
-    labels, n_sweeps = _propagate(
+    labels, n_sweeps, n_steps = _propagate(
         lambda l: sweep(l, core), init, core, max_iters, doubling_steps
     )
 
@@ -308,9 +330,9 @@ def _dbscan_block_sparse(
     is_root = core_o & (labels == jnp.arange(n, dtype=jnp.int32))
     n_clusters = jnp.sum(is_root.astype(jnp.int32))
     labels = jnp.where(labels == SENTINEL, NOISE, labels)
-    return DBSCANResult(labels, core_o, n_clusters, n_sweeps, pairs.n_active,
-                        jnp.asarray(pairs.rows.shape[0], jnp.int32),
-                        ~use_sparse)
+    return DBSCANResult(labels, core_o, n_clusters, n_sweeps, n_steps,
+                        pairs.n_active,
+                        jnp.asarray(pairs.rows.shape[0], jnp.int32), ~use_sparse)
 
 
 def relabel_dense(labels: jax.Array, max_clusters: int) -> jax.Array:

@@ -118,6 +118,7 @@ class Phase1Stats(NamedTuple):
     """What one shard's phase 1 did (host-side counters, not wire data)."""
 
     sweeps: jax.Array             # () i32 — label sweeps to convergence
+    doubling_steps: jax.Array     # () i32 — pointer-doubling gathers run
     tile_pairs_active: jax.Array  # () i32 — tile pairs within eps
     tile_pairs: jax.Array         # () i32 — tile pairs in all (T²)
     dense_fallback: jax.Array     # () bool — sweeps ran the dense kernels
@@ -138,8 +139,9 @@ def local_phase_stats(
         )
         dense = dbscan_mod.relabel_dense(res.labels, c_budget)
         n_clusters = res.n_clusters
-        stats = Phase1Stats(res.n_sweeps, res.tile_pairs_active,
-                            res.tile_pairs, res.dense_fallback)
+        stats = Phase1Stats(res.n_sweeps, res.n_doubling_steps,
+                            res.tile_pairs_active, res.tile_pairs,
+                            res.dense_fallback)
     elif cfg.local_algo == "kmeans":
         if key is None:
             key = jax.random.PRNGKey(0)
@@ -147,7 +149,7 @@ def local_phase_stats(
         dense = km.labels
         n_clusters = jnp.asarray(min(cfg.kmeans_k, c_budget), jnp.int32)
         zero = jnp.asarray(0, jnp.int32)
-        stats = Phase1Stats(zero, zero, zero, jnp.asarray(False))
+        stats = Phase1Stats(zero, zero, zero, zero, jnp.asarray(False))
     else:  # pragma: no cover
         raise ValueError(cfg.local_algo)
 

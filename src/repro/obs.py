@@ -31,8 +31,8 @@ The spans of the fit path, and what each holds:
 * ``ddc.refresh`` -- a refresh that had work to do (``dirty``, ``mode``).
 * ``ddc.phase1`` -- one shard's phase 1 in the stream engine, from
   ``local_phase`` to the end of the shard's host copy (``shard``,
-  ``attempt``; and, when phase 1 ran, ``sweeps``, ``tile_pairs_active``,
-  ``tile_pairs``, ``dense_fallback``).
+  ``attempt``; and, when phase 1 ran, ``sweeps``, ``doubling_steps``,
+  ``tile_pairs_active``, ``tile_pairs``, ``dense_fallback``).
 * ``ddc.aggregate`` -- the merge, the global labels and the snapshot
   publish, which ends in a host read of the merged set (``mode``,
   ``staged``).

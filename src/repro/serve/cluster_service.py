@@ -111,8 +111,9 @@ class StreamConfig:
 
 
 # Phase-1 counters of ``ServiceCounters``, kept per service.
-PHASE1_COUNTERS = ("phase1_runs", "phase1_sweeps", "phase1_tile_pairs_active",
-                   "phase1_tile_pairs", "phase1_dense_fallbacks")
+PHASE1_COUNTERS = ("phase1_runs", "phase1_sweeps", "phase1_doubling_steps",
+                   "phase1_tile_pairs_active", "phase1_tile_pairs",
+                   "phase1_dense_fallbacks")
 
 
 # ---------------------------------------------------------------------------
@@ -792,12 +793,14 @@ class ShardControlPlane:
         """Fold one phase-1 run's stats into the counters; returns them
         as span attributes."""
         attrs = {"sweeps": int(st.sweeps),
+                 "doubling_steps": int(st.doubling_steps),
                  "tile_pairs_active": int(st.tile_pairs_active),
                  "tile_pairs": int(st.tile_pairs),
                  "dense_fallback": bool(st.dense_fallback)}
         c = self.phase1_counts
         c["phase1_runs"] += 1
         c["phase1_sweeps"] += attrs["sweeps"]
+        c["phase1_doubling_steps"] += attrs["doubling_steps"]
         c["phase1_tile_pairs_active"] += attrs["tile_pairs_active"]
         c["phase1_tile_pairs"] += attrs["tile_pairs"]
         c["phase1_dense_fallbacks"] += int(attrs["dense_fallback"])

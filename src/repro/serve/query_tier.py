@@ -315,10 +315,12 @@ class ServiceCounters:
     fenced_deltas: int = 0          # duplicates the epoch fence dropped
     journal_entries: int = 0        # write-ahead journal records
     # Phase 1 in the stream engine: local clusterings run, and their
-    # label sweeps to convergence, tile pairs within eps, tile pairs in
-    # all, and runs whose sweeps fell back to the dense kernels.
+    # label sweeps to convergence, pointer-doubling gathers, tile pairs
+    # within eps, tile pairs in all, and runs whose sweeps fell back to
+    # the dense kernels.
     phase1_runs: int = 0
     phase1_sweeps: int = 0
+    phase1_doubling_steps: int = 0
     phase1_tile_pairs_active: int = 0
     phase1_tile_pairs: int = 0
     phase1_dense_fallbacks: int = 0
@@ -395,6 +397,7 @@ class ServiceStats:
             "refits": c.refits,
             "phase1_runs": c.phase1_runs,
             "phase1_sweeps": c.phase1_sweeps,
+            "phase1_doubling_steps": c.phase1_doubling_steps,
             "phase1_tile_pairs_active": c.phase1_tile_pairs_active,
             "phase1_tile_pairs": c.phase1_tile_pairs,
             "phase1_dense_fallbacks": c.phase1_dense_fallbacks,

@@ -6,6 +6,9 @@ the block-sparse dbscan path must match the dense reference **bit-exactly**
 — on random, clustered, and adversarial (all points in one cell) layouts.
 Pallas kernels run in interpret mode (CPU container).
 """
+import math
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -170,6 +173,41 @@ class TestDBSCANBlockSparse:
         assert (np.asarray(res.labels)[220:] == db.NOISE).all()
 
 
+def fixed_trip_propagate(sweep_fn, init, core, max_iters, doubling_steps):
+    """The reference: sweeps each followed by all ``doubling_steps``
+    shortcut steps, run whether or not they change a label (the loop the
+    fixed-point stop replaced).  Same contract as ``db._propagate``."""
+    n = init.shape[0]
+
+    def shortcut(_, l):
+        jumped = jnp.take(l, jnp.where(l < n, l, 0))
+        return jnp.minimum(l, jnp.where(l < n, jumped, l))
+
+    def cond(state):
+        _, changed, it = state
+        return changed & (it < max_iters)
+
+    def body(state):
+        labels, _, it = state
+        swept = sweep_fn(labels)
+        new = jnp.where(core, jnp.minimum(labels, swept), labels)
+        new = jax.lax.fori_loop(0, doubling_steps, shortcut, new)
+        return new, jnp.any(new != labels), it + 1
+
+    labels, _, n_sweeps = jax.lax.while_loop(
+        cond, body, (init, jnp.asarray(True), jnp.asarray(0, jnp.int32)))
+    return labels, n_sweeps, n_sweeps * doubling_steps
+
+
+def _doubling_case(name):
+    if name == "worm":
+        return make_worm(800, seed=3), 0.02, 5
+    if name == "blobs":
+        return spatial.make_blobs(400, 5, seed=2)[0], 0.05, 5
+    spec = spatial.PHASE2_LAYOUTS[name]
+    return spec["make"](600), spec["eps"], spec["min_pts"]
+
+
 class TestPointerDoubling:
     def test_labels_identical(self):
         pts, _ = spatial.make_blobs(400, 5, seed=2)
@@ -198,3 +236,35 @@ class TestPointerDoubling:
         got = db.dbscan(jnp.asarray(worm), jnp.ones(800, bool), 0.02, 5,
                         block_sparse="always", bt=128)
         np.testing.assert_array_equal(np.asarray(got.labels), want)
+
+    @pytest.mark.parametrize("block_sparse", ["never", "always"])
+    @pytest.mark.parametrize("layout", ["worm", "blobs", "rings"])
+    def test_bit_identical_to_fixed_trips(self, layout, block_sparse,
+                                          monkeypatch):
+        """Doubling that stops at its fixed point returns what the
+        fixed-trip loop returns, in no more gathers."""
+        pts, eps, min_pts = _doubling_case(layout)
+        n = len(pts)
+        args = (jnp.asarray(pts), jnp.ones(n, bool), eps, min_pts)
+        kw = dict(block_sparse=block_sparse, bt=128)
+        got = db.dbscan(*args, **kw)
+        with monkeypatch.context() as m:
+            m.setattr(db, "_propagate", fixed_trip_propagate)
+            # A fresh function, so the trace cache cannot hand back the
+            # program traced with the program's own loop.
+            want = jax.jit(lambda: db.dbscan.__wrapped__(*args, **kw))()
+        for field in ("labels", "core", "n_clusters", "n_sweeps"):
+            np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                          np.asarray(getattr(want, field)),
+                                          err_msg=field)
+        cap = int(want.n_doubling_steps)
+        assert cap == int(want.n_sweeps) * math.ceil(math.log2(n))
+        assert 1 <= int(got.n_doubling_steps) <= cap
+        if layout == "worm":
+            assert int(got.n_doubling_steps) < cap
+
+    def test_no_steps_without_doubling(self):
+        pts, eps, min_pts = _doubling_case("worm")
+        res = db.dbscan(jnp.asarray(pts), jnp.ones(len(pts), bool), eps,
+                        min_pts, pointer_doubling=False, block_sparse="never")
+        assert int(res.n_sweeps) > 1 and int(res.n_doubling_steps) == 0

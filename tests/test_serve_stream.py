@@ -12,6 +12,8 @@ accounting of delta vs full re-merge, and the eviction regressions
 Big sweeps are marked ``slow`` (separate non-blocking CI job); the
 unmarked subset keeps the blocking tier-1 run light.
 """
+import json
+
 import numpy as np
 import pytest
 
@@ -296,6 +298,8 @@ class TestFitSpansAndPhase1Counters:
         c = model.stats().counters
         assert c.phase1_runs == self.K
         assert c.phase1_sweeps == sum(s.attrs["sweeps"] for s in p1) > 0
+        assert c.phase1_doubling_steps \
+            == sum(s.attrs["doubling_steps"] for s in p1) > 0
         tiles = (N // self.K // 128) ** 2
         assert c.phase1_tile_pairs == self.K * tiles \
             == sum(s.attrs["tile_pairs"] for s in p1)
@@ -306,10 +310,21 @@ class TestFitSpansAndPhase1Counters:
             == sum(int(s.attrs["dense_fallback"]) for s in p1)
         d = model.stats().as_dict()
         assert all(d[k] == getattr(c, k) for k in PHASE1_COUNTERS)
-        back = DDC.load(model.save(str(tmp_path / "snap")))
+        path = model.save(str(tmp_path / "snap"))
+        back = DDC.load(path)
         c2 = back.stats().counters
         assert all(getattr(c2, k) == getattr(c, k) for k in PHASE1_COUNTERS)
+        assert c2.phase1_doubling_steps == c.phase1_doubling_steps
         np.testing.assert_array_equal(back.labels_, labels)
+        # A snapshot written before the doubling counter loads it as 0.
+        manifest = tmp_path / "snap" / "manifest.json"
+        doc = json.loads(manifest.read_text())
+        assert doc["state"].pop("phase1_doubling_steps") \
+            == c.phase1_doubling_steps
+        manifest.write_text(json.dumps(doc))
+        old = DDC.load(path).stats().counters
+        assert old.phase1_doubling_steps == 0
+        assert old.phase1_sweeps == c.phase1_sweeps
 
     def test_stats_entry_matches_local_phase_bit_for_bit(self):
         """The stream engine's phase-1 entry gives the same labels and
