@@ -122,6 +122,7 @@ class Phase1Stats(NamedTuple):
     tile_pairs_active: jax.Array  # () i32 — tile pairs within eps
     tile_pairs: jax.Array         # () i32 — tile pairs in all (T²)
     dense_fallback: jax.Array     # () bool — sweeps ran the dense kernels
+    truncated: jax.Array          # () i32 — contours cut at max_verts
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -130,7 +131,9 @@ def local_phase_stats(
 ) -> Tuple[jax.Array, ClusterSet, Phase1Stats]:
     """``local_phase`` plus its ``Phase1Stats``, from the same program.
     The tile-pair counts and the fallback flag are 0 / False off the
-    block-sparse path, and every count is 0 for K-Means."""
+    block-sparse path, and every propagation count is 0 for K-Means.
+    ``truncated`` counts the clusters whose boundary cells outnumber
+    ``max_verts``, so their contour kept only the first ``max_verts``."""
     c_budget = cfg.max_clusters
     if cfg.local_algo == "dbscan":
         res = dbscan_mod.dbscan(
@@ -139,9 +142,8 @@ def local_phase_stats(
         )
         dense = dbscan_mod.relabel_dense(res.labels, c_budget)
         n_clusters = res.n_clusters
-        stats = Phase1Stats(res.n_sweeps, res.n_doubling_steps,
-                            res.tile_pairs_active, res.tile_pairs,
-                            res.dense_fallback)
+        counts = (res.n_sweeps, res.n_doubling_steps, res.tile_pairs_active,
+                  res.tile_pairs, res.dense_fallback)
     elif cfg.local_algo == "kmeans":
         if key is None:
             key = jax.random.PRNGKey(0)
@@ -149,7 +151,7 @@ def local_phase_stats(
         dense = km.labels
         n_clusters = jnp.asarray(min(cfg.kmeans_k, c_budget), jnp.int32)
         zero = jnp.asarray(0, jnp.int32)
-        stats = Phase1Stats(zero, zero, zero, zero, jnp.asarray(False))
+        counts = (zero, zero, zero, zero, jnp.asarray(False))
     else:  # pragma: no cover
         raise ValueError(cfg.local_algo)
 
@@ -161,15 +163,16 @@ def local_phase_stats(
 
         def one_contour(cid):
             m = mask & (dense == cid)
-            pts, cnt = geometry.extract_contour(
+            return geometry.contour_cells(
                 points, m, cfg.bounds, cfg.grid, cfg.max_verts
             )
-            return pts, cnt
 
-        contours, counts = jax.vmap(one_contour)(jnp.arange(c_budget))
+        contours, n_verts, cells = jax.vmap(one_contour)(jnp.arange(c_budget))
+    cut = jnp.sum((valid & (cells > cfg.max_verts)).astype(jnp.int32))
+    stats = Phase1Stats(*counts, truncated=cut)
     cs = ClusterSet(
         contours=contours,
-        counts=jnp.where(valid, counts, 0),
+        counts=jnp.where(valid, n_verts, 0),
         sizes=sizes,
         valid=valid,
         overflow=n_clusters > c_budget,
@@ -351,6 +354,14 @@ def merge_from_d2(batch: ClusterSet, pair_d2: jax.Array,
                   cfg: DDCConfig,
                   exclude: jax.Array | None = None
                   ) -> Tuple[ClusterSet, jax.Array]:
+    """``_fold`` without its count of cut contours: (merged, maps)."""
+    merged, maps, _ = _fold(batch, pair_d2, cfg, exclude)
+    return merged, maps
+
+
+def _fold(batch: ClusterSet, pair_d2: jax.Array, cfg: DDCConfig,
+          exclude: jax.Array | None = None
+          ) -> Tuple[ClusterSet, jax.Array, jax.Array]:
     """The merge fold given a precomputed slot×slot distance matrix:
     overlap predicate → transitive closure → ranked rebuild.  Everything
     downstream of the matrix is a pure function of (batch, pair_d2), so
@@ -363,7 +374,11 @@ def merge_from_d2(batch: ClusterSet, pair_d2: jax.Array,
     -1, their sizes and overflow flags ignored), so healthy shards keep
     merging and the matrix stays pristine for a bit-exact rejoin.
     ``exclude=None`` traces separately and is the identical healthy
-    path."""
+    path.
+
+    Returns (merged, maps, cut): ``cut`` () i32 counts the merged
+    contours whose boundary cells outnumber ``max_verts`` (grid rebuild
+    only; 0 under ``merge_refine="fps"``)."""
     c, v = cfg.max_clusters, cfg.max_verts
     k = batch.valid.shape[0]
     m = k * c
@@ -408,15 +423,16 @@ def merge_from_d2(batch: ClusterSet, pair_d2: jax.Array,
         member = slot_of_old == slot                            # (M,)
         pmask = (vert_valid & member[:, None]).reshape(m * v)
         if cfg.merge_refine == "grid":
-            pts, cnt = geometry.extract_contour(
+            pts, cnt, cells = geometry.contour_cells(
                 flat_pts, pmask, cfg.bounds, cfg.grid, v
             )
         else:
             pts, cnt = geometry.farthest_point_subsample(flat_pts, pmask, v)
+            cells = cnt
         size = jnp.sum(jnp.where(member, sizes, 0))
-        return pts, cnt, size, size > 0
+        return pts, cnt, cells, size, size > 0
 
-    nc, ncnt, nsize, nvalid = jax.vmap(build)(jnp.arange(c))
+    nc, ncnt, ncells, nsize, nvalid = jax.vmap(build)(jnp.arange(c))
     merged = ClusterSet(
         contours=nc,
         counts=jnp.where(nvalid, ncnt, 0),
@@ -424,7 +440,8 @@ def merge_from_d2(batch: ClusterSet, pair_d2: jax.Array,
         valid=nvalid,
         overflow=overflow,
     )
-    return merged, slot_of_old.reshape(k, c)
+    cut = jnp.sum((nvalid & (ncells > v)).astype(jnp.int32))
+    return merged, slot_of_old.reshape(k, c), cut
 
 
 def merge_delta(batch: ClusterSet, pair_d2: jax.Array | None,
@@ -489,7 +506,15 @@ def merge_many(batch: ClusterSet, cfg: DDCConfig) -> Tuple[ClusterSet, jax.Array
     permutes ``maps`` rows but yields the identical merged clustering
     (components are ranked by total member count, ties by slot index).
     """
-    return merge_from_d2(batch, contour_pair_d2(batch, cfg), cfg)
+    merged, maps, _ = _merge_counted(batch, cfg)
+    return merged, maps
+
+
+def _merge_counted(batch: ClusterSet, cfg: DDCConfig
+                   ) -> Tuple[ClusterSet, jax.Array, jax.Array]:
+    """``merge_many`` plus the count of merged contours cut at
+    ``max_verts``, for the phase-2 schedules inside ``shard_map``."""
+    return _fold(batch, contour_pair_d2(batch, cfg), cfg)
 
 
 def merge_pair(
@@ -568,7 +593,11 @@ def merge_sync(cs: ClusterSet, cfg: DDCConfig, axis: str,
     batched merge_many over all K·C slots (the paper's synchronous model:
     everyone waits for the slowest, then merges).  Collective bytes per
     lane: (K−1)·B.  Returns (global ClusterSet, local-slot → global-slot
-    map (C,)).
+    map (C,), merged contours cut at ``max_verts``).
+
+    Every schedule counts a merged contour that was cut on one lane only,
+    the lowest of the lanes that computed the same merge, so the lanes'
+    counts add up to the contours cut.
     """
     k = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
@@ -576,9 +605,9 @@ def merge_sync(cs: ClusterSet, cfg: DDCConfig, axis: str,
         meter.add_collective(k * (k - 1), _wire_bytes(cs))
         meter.add_merge(k, cfg.max_clusters)
     gathered = jax.lax.all_gather(cs, axis)   # pytree: leaves (K, ...)
-    gcs, maps = merge_many(gathered, cfg)
+    gcs, maps, cut = _merge_counted(gathered, cfg)
     my_map = jnp.take(maps, me, axis=0)
-    return gcs, jnp.where(cs.valid, my_map, -1)
+    return gcs, jnp.where(cs.valid, my_map, -1), jnp.where(me == 0, cut, 0)
 
 
 def merge_async(cs: ClusterSet, cfg: DDCConfig, axis: str,
@@ -587,6 +616,8 @@ def merge_async(cs: ClusterSet, cfg: DDCConfig, axis: str,
     merge rounds; merge compute of round ℓ overlaps the round ℓ+1 permute
     in XLA's schedule.  Matches the paper's asynchronous model (merge as
     soon as the partner is ready).  Collective bytes per lane: log2(K)·B.
+    Each round is a ``merge_pair`` of the two partners' sets, under the
+    named scope ``p2.butterfly``.  Returns what ``merge_sync`` returns.
     """
     k = jax.lax.axis_size(axis)
     assert k & (k - 1) == 0, f"async schedule needs power-of-two shards, got {k}"
@@ -595,22 +626,28 @@ def merge_async(cs: ClusterSet, cfg: DDCConfig, axis: str,
     my_map = jnp.where(cs.valid, my_map, -1)
 
     acc = cs
+    cut = jnp.asarray(0, jnp.int32)
     rounds = k.bit_length() - 1
     for level in range(rounds):
         stride = 1 << level
-        perm = [(i, i ^ stride) for i in range(k)]
-        partner_cs = _permute(acc, axis, perm, meter)
-        low = (me & stride) == 0
-        a = jax.tree.map(lambda s, p: jnp.where(low, s, p), acc, partner_cs)
-        b = jax.tree.map(lambda s, p: jnp.where(low, p, s), acc, partner_cs)
-        # `a`/`b` ordering is lane-consistent, so both sides compute the
-        # identical merged buffer (deterministic merge).
-        if meter is not None:
-            meter.add_merge(2, cfg.max_clusters)
-        acc, map_a, map_b = merge_pair(a, b, cfg)
-        mine = jnp.where(low, map_a, map_b)
-        my_map = jnp.where(my_map >= 0, mine[jnp.clip(my_map, 0)], -1)
-    return acc, my_map
+        with jax.named_scope("p2.butterfly"):
+            perm = [(i, i ^ stride) for i in range(k)]
+            partner_cs = _permute(acc, axis, perm, meter)
+            low = (me & stride) == 0
+            a = jax.tree.map(lambda s, p: jnp.where(low, s, p), acc, partner_cs)
+            b = jax.tree.map(lambda s, p: jnp.where(low, p, s), acc, partner_cs)
+            # `a`/`b` ordering is lane-consistent, so both sides compute the
+            # identical merged buffer (deterministic merge).
+            if meter is not None:
+                meter.add_merge(2, cfg.max_clusters)
+            pair = jax.tree.map(lambda x, y: jnp.stack([x, y]), a, b)
+            acc, maps, round_cut = _merge_counted(pair, cfg)
+            mine = jnp.where(low, maps[0], maps[1])
+            my_map = jnp.where(my_map >= 0, mine[jnp.clip(my_map, 0)], -1)
+            # The 2·stride lanes of this round's group merged alike.
+            owner = (me & (2 * stride - 1)) == 0
+            cut = cut + jnp.where(owner, round_cut, 0)
+    return acc, my_map, cut
 
 
 def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
@@ -625,6 +662,7 @@ def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
     ((D-1)/D of lanes send), + one broadcast at the end — between sync's
     (K-1)·B all-gather and async's log2(K)·B butterfly.  Unlike the
     butterfly, non-leaders idle above their level (the paper's Fig. 1).
+    Returns what ``merge_sync`` returns.
     """
     k = jax.lax.axis_size(axis)
     d = cfg.tree_degree
@@ -632,6 +670,7 @@ def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
     my_map = jnp.where(cs.valid, jnp.arange(cfg.max_clusters, dtype=jnp.int32), -1)
 
     acc = cs
+    cut = jnp.asarray(0, jnp.int32)
     stride = 1
     while stride < k:
         # Group = lanes {base, base+stride, ..., base+(D-1)*stride};
@@ -650,7 +689,8 @@ def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
         if meter is not None:
             meter.add_merge(len(batch), cfg.max_clusters)
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *batch)
-        merged, maps = merge_many(stacked, cfg)
+        merged, maps, level_cut = _merge_counted(stacked, cfg)
+        cut = cut + jnp.where(me % (stride * d) == 0, level_cut, 0)
         # Leaders fold; everyone else keeps their acc (their map will be
         # resolved by the broadcast below).  Slot 0 of the batch is the
         # leader's own accumulator.
@@ -684,7 +724,7 @@ def merge_tree(cs: ClusterSet, cfg: DDCConfig, axis: str,
     # leader level).
     resolved = match_to_global(cs, gcs, cfg)
     my_map = jnp.where(me == 0, my_map, resolved)
-    return gcs, my_map
+    return gcs, my_map, cut
 
 
 def match_to_global(cs: ClusterSet, gcs: ClusterSet, cfg: DDCConfig) -> jax.Array:
@@ -730,24 +770,29 @@ def ddc_shard(
 ):
     """Full DDC inside ``shard_map``: phase 1 locally, phase 2 across
     ``axis``.  Returns (global labels for local points (n,),
-    global ClusterSet, local→global slot map)."""
-    dense, cs = local_phase(points, mask, cfg, key)
+    global ClusterSet, local→global slot map, (this lane's
+    ``Phase1Stats``, merged contours it counts as cut), the last with
+    every leaf shaped (1,) to stack along ``axis``)."""
+    dense, cs, stats = local_phase_stats(points, mask, cfg, key)
     if cfg.schedule == "sync":
-        gcs, my_map = merge_sync(cs, cfg, axis, meter)
+        gcs, my_map, cut = merge_sync(cs, cfg, axis, meter)
     elif cfg.schedule == "tree":
-        gcs, my_map = merge_tree(cs, cfg, axis, meter)
+        gcs, my_map, cut = merge_tree(cs, cfg, axis, meter)
     else:
-        gcs, my_map = merge_async(cs, cfg, axis, meter)
+        gcs, my_map, cut = merge_async(cs, cfg, axis, meter)
     glabels = jnp.where(dense >= 0, my_map[jnp.clip(dense, 0)], -1)
-    return glabels, gcs, my_map
+    lane = jax.tree.map(lambda x: jnp.reshape(x, (1,)), (stats, cut))
+    return glabels, gcs, my_map, lane
 
 
 def make_ddc_fn(mesh, axis: str, cfg: DDCConfig, meter: CommMeter | None = None):
     """Build the jit-able distributed DDC entry point over ``mesh``.
 
-    points: (N, 2) sharded along ``axis``; mask: (N,).  An optional
-    ``meter`` collects static comm-volume counters while the function
-    traces (see CommMeter).
+    points: (N, 2) sharded along ``axis``; mask: (N,).  Returns
+    (global labels (N,), global ClusterSet, slot maps (K·C,), (per-lane
+    ``Phase1Stats``, per-lane merged contours cut), the last with (K,)
+    leaves).  An optional ``meter`` collects static comm-volume counters
+    while the function traces (see CommMeter).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -757,7 +802,7 @@ def make_ddc_fn(mesh, axis: str, cfg: DDCConfig, meter: CommMeter | None = None)
             lambda p, m: ddc_shard(p, m, cfg, axis, meter=meter),
             mesh=mesh,
             in_specs=(P(axis, None), P(axis)),
-            out_specs=(P(axis), P(), P(axis)),
+            out_specs=(P(axis), P(), P(axis), P(axis)),
             check_vma=False,
         )
         return fn(points, mask)

@@ -199,8 +199,9 @@ def cells_to_points(
 ) -> Tuple[Array, Array]:
     """Select up to ``max_verts`` active cells and return their centres.
 
-    Returns (points (max_verts, 2), count ()).  Deterministic: row-major
-    top-k on the active flag.
+    Returns (points (max_verts, 2), count (), active cells ()); the
+    contour was cut when the active cells outnumber ``max_verts``.
+    Deterministic: row-major top-k on the active flag.
     """
     grid = cells.shape[0]
     x0, y0, x1, y1 = bounds
@@ -219,7 +220,20 @@ def cells_to_points(
     cy = y0 + (by.astype(jnp.float32) + 0.5) / sy
     pts = jnp.stack([cx, cy], axis=-1)
     pts = jnp.where(valid[:, None], pts, 0.0)
-    return pts, jnp.minimum(n_active, max_verts)
+    return pts, jnp.minimum(n_active, max_verts), n_active
+
+
+def contour_cells(
+    points: Array,
+    mask: Array,
+    bounds: Tuple[float, float, float, float],
+    grid: int,
+    max_verts: int,
+) -> Tuple[Array, Array, Array]:
+    """``extract_contour`` plus the number of boundary cells it chose
+    from: (contour (max_verts, 2), n_verts (), boundary cells ())."""
+    occ = grid_occupancy(points, mask, bounds, grid)
+    return cells_to_points(grid_boundary(occ), bounds, max_verts)
 
 
 def extract_contour(
@@ -234,9 +248,8 @@ def extract_contour(
     Returns (contour (max_verts, 2), n_verts ()).  This is DDC's data
     reduction: the contour is the cluster's network representation.
     """
-    occ = grid_occupancy(points, mask, bounds, grid)
-    boundary = grid_boundary(occ)
-    return cells_to_points(boundary, bounds, max_verts)
+    pts, cnt, _ = contour_cells(points, mask, bounds, grid, max_verts)
+    return pts, cnt
 
 
 def convex_hull_jax(points: Array, mask: Array, max_verts: int) -> Tuple[Array, Array]:

@@ -56,7 +56,7 @@ def curate(
         pts = np.pad(embeddings, ((0, pad), (0, 0)))
         mask = np.arange(len(pts)) < n
         run = ddc.make_ddc_fn(mesh, axis, cfg)
-        glabels, gcs, _ = run(jnp.asarray(pts), jnp.asarray(mask))
+        glabels, gcs, _, _ = run(jnp.asarray(pts), jnp.asarray(mask))
         labels = np.asarray(glabels)[:n]
         wire = cfg.buffer_bytes() * (k.bit_length() - 1 if cfg.schedule == "async" else k - 1)
         exchanged = wire / (n * embeddings.itemsize * embeddings.shape[1])

@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Type
 
 import numpy as np
 
+from repro import obs
 from repro.core import ddc as core_ddc
 from repro.ddc.config import ConfigError, DDCConfig
 
@@ -190,6 +191,11 @@ class _BufferedBatchBackend(Backend):
         self._snapshot = None
         self._snapshot_version = 0
         self.refits = 0           # monotonic: full-pipeline recomputes
+        # ``ServiceCounters.phase1_*``: summed over every lane's phase 1
+        # (``jit``; the ``host`` oracle leaves them 0).
+        from repro.serve.cluster_service import PHASE1_COUNTERS
+
+        self.phase1_counts = dict.fromkeys(PHASE1_COUNTERS, 0)
 
     def fit(self, points: np.ndarray, t: float | None = None) -> None:
         pts = np.asarray(points, np.float32).reshape(-1, 2)
@@ -307,6 +313,7 @@ class _BufferedBatchBackend(Backend):
             query_rows=tc.get("query_rows", 0),
             deadline_misses=tc.get("deadline_misses", 0),
             degraded_queries=tc.get("degraded_queries", 0),
+            **self.phase1_counts,
         )
         gauges = qt.ServiceGauges(
             shards=self.cfg.shards,
@@ -394,6 +401,7 @@ class JitBackend(_BufferedBatchBackend):
     def __init__(self, cfg: DDCConfig, meter=None, faults=None):
         super().__init__(cfg, meter, faults=faults)
         self._runners: dict = {}
+        self._meshes: dict = {}
 
     def make_runner(self, n_points: int):
         """The jitted distributed entry point for ``n_points`` inputs
@@ -412,13 +420,19 @@ class JitBackend(_BufferedBatchBackend):
         if key not in self._runners:
             if len(self._runners) >= 4:   # drop stale executables: every
                 self._runners.clear()     # distinct width is a recompile
+                self._meshes.clear()
             mesh = mesh_mod.make_host_mesh(k)
+            self._meshes[key] = mesh
             self._runners[key] = core_ddc.make_ddc_fn(
                 mesh, "data", self.cfg.core(), self.meter)
         return self._runners[key]
 
     def _refit(self) -> np.ndarray:
-        import jax.numpy as jnp
+        import jax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+
+        from repro.serve import cluster_service
 
         k = self.cfg.shards
         lens = [len(p) for p in self._shard_pts]
@@ -429,18 +443,33 @@ class JitBackend(_BufferedBatchBackend):
         # whole shard_map pipeline at every new max-shard length.
         cap = max(lens)
         cap = max(16, 1 << (cap - 1).bit_length())
-        padded = np.zeros((k, cap, 2), np.float32)
-        mask = np.zeros((k, cap), bool)
-        for s, p in enumerate(self._shard_pts):
-            padded[s, :len(p)] = p
-            mask[s, :len(p)] = True
-        run = self.make_runner(k * cap)
-        glabels, _, _ = run(
-            jnp.asarray(padded.reshape(k * cap, 2)),
-            jnp.asarray(mask.reshape(k * cap)))
-        flat = np.asarray(glabels).reshape(k, cap)
-        return np.concatenate(
-            [flat[s, :n] for s, n in enumerate(lens)]).astype(np.int32)
+        with obs.span("ddc.refit", backend=self.name, shards=k, cap=cap):
+            padded = np.zeros((k, cap, 2), np.float32)
+            mask = np.zeros((k, cap), bool)
+            for s, p in enumerate(self._shard_pts):
+                padded[s, :len(p)] = p
+                mask[s, :len(p)] = True
+            run = self.make_runner(k * cap)
+            # Each lane's block straight to its own device.
+            mesh = self._meshes[k * cap]
+            x = jax.device_put(padded.reshape(k * cap, 2),
+                               NamedSharding(mesh, P("data", None)))
+            m = jax.device_put(mask.reshape(k * cap),
+                               NamedSharding(mesh, P("data")))
+            with obs.span("ddc.run") as attrs:
+                glabels, gcs, _, lanes = run(x, m)
+                flat, overflow, (st, cut) = jax.device_get(
+                    (glabels, gcs.overflow, lanes))
+                per_lane = [cluster_service.count_phase1(
+                    self.phase1_counts, core_ddc.Phase1Stats(*leaves))
+                    for leaves in zip(*st)]
+                for key in per_lane[0]:
+                    attrs[key] = [lane[key] for lane in per_lane]
+                attrs["overflow"] = bool(overflow)
+                attrs["truncated"] = int(st.truncated.sum() + cut.sum())
+            flat = flat.reshape(k, cap)
+            return np.concatenate(
+                [flat[s, :n] for s, n in enumerate(lens)]).astype(np.int32)
 
 
 @register_backend("stream")

@@ -38,6 +38,16 @@ The spans of the fit path, and what each holds:
   ``staged``).
 * ``ddc.live`` -- ``ShardControlPlane.live``, the host copy behind
   ``DDC.labels_`` (``n_live``).
+* ``ddc.refit`` -- the ``jit`` backend's whole pipeline, run by the
+  first read after a write (``DDC.labels_``): padding, placement on the
+  mesh, the program and the label concat (``backend``, ``shards``,
+  ``cap``); one trace per refit.
+* ``ddc.run`` -- its child: from the call of the ``make_ddc_fn``
+  program to the end of its one host read of labels and stats.  Per-lane
+  lists ``sweeps``, ``doubling_steps``, ``tile_pairs_active``,
+  ``tile_pairs``, ``dense_fallback``; ``overflow``, the global set's
+  cluster-budget flag; ``truncated``, the local and merged contours cut
+  at ``max_verts``.
 """
 from __future__ import annotations
 
@@ -52,7 +62,7 @@ from typing import NamedTuple, Optional
 import jax
 
 SPAN_NAMES = ("ddc.fit", "ddc.ingest", "ddc.refresh", "ddc.phase1",
-              "ddc.aggregate", "ddc.live")
+              "ddc.aggregate", "ddc.live", "ddc.refit", "ddc.run")
 RING_SIZE = 4096
 
 # (span_id, trace_id) of the innermost open span in this context.

@@ -116,6 +116,23 @@ PHASE1_COUNTERS = ("phase1_runs", "phase1_sweeps", "phase1_doubling_steps",
                    "phase1_dense_fallbacks")
 
 
+def count_phase1(counts: dict, st: ddc.Phase1Stats) -> dict:
+    """Fold one phase-1 run's host-side stats into ``counts`` (keyed by
+    ``PHASE1_COUNTERS``); returns them as span attributes."""
+    attrs = {"sweeps": int(st.sweeps),
+             "doubling_steps": int(st.doubling_steps),
+             "tile_pairs_active": int(st.tile_pairs_active),
+             "tile_pairs": int(st.tile_pairs),
+             "dense_fallback": bool(st.dense_fallback)}
+    counts["phase1_runs"] += 1
+    counts["phase1_sweeps"] += attrs["sweeps"]
+    counts["phase1_doubling_steps"] += attrs["doubling_steps"]
+    counts["phase1_tile_pairs_active"] += attrs["tile_pairs_active"]
+    counts["phase1_tile_pairs"] += attrs["tile_pairs"]
+    counts["phase1_dense_fallbacks"] += int(attrs["dense_fallback"])
+    return attrs
+
+
 # ---------------------------------------------------------------------------
 # Jitted state-update kernels (static shapes; buffers donated)
 # ---------------------------------------------------------------------------
@@ -789,23 +806,6 @@ class ShardControlPlane:
         recompute the global labels of every shard's points."""
         raise NotImplementedError
 
-    def _count_phase1(self, st: ddc.Phase1Stats) -> dict:
-        """Fold one phase-1 run's stats into the counters; returns them
-        as span attributes."""
-        attrs = {"sweeps": int(st.sweeps),
-                 "doubling_steps": int(st.doubling_steps),
-                 "tile_pairs_active": int(st.tile_pairs_active),
-                 "tile_pairs": int(st.tile_pairs),
-                 "dense_fallback": bool(st.dense_fallback)}
-        c = self.phase1_counts
-        c["phase1_runs"] += 1
-        c["phase1_sweeps"] += attrs["sweeps"]
-        c["phase1_doubling_steps"] += attrs["doubling_steps"]
-        c["phase1_tile_pairs_active"] += attrs["tile_pairs_active"]
-        c["phase1_tile_pairs"] += attrs["tile_pairs"]
-        c["phase1_dense_fallbacks"] += int(attrs["dense_fallback"])
-        return attrs
-
     # -- cluster tracking (DESIGN.md §14) -----------------------------------
 
     @property
@@ -1280,7 +1280,7 @@ class ClusterService(ShardControlPlane):
                 self._dense = _set_row(self._dense, dense, i)
                 payload, st = _cs_to_host(cs, st)
                 if st is not None:
-                    attrs.update(self._count_phase1(st))
+                    attrs.update(count_phase1(self.phase1_counts, st))
             return payload, cs
 
         return self._exchange_deltas(dirty, produce), None

@@ -30,7 +30,7 @@ def check_ddc_sync_async_identical():
         cfg = ddc.DDCConfig(eps=0.05, min_pts=5, max_clusters=16, max_verts=64,
                             grid=96, schedule=sched, tree_degree=deg)
         run = ddc.make_ddc_fn(mesh, "data", cfg)
-        glabels, gcs, _ = run(jnp.asarray(pts), jnp.ones(len(pts), bool))
+        glabels, gcs, _, _ = run(jnp.asarray(pts), jnp.ones(len(pts), bool))
         results[f"{sched}{deg}"] = (np.asarray(glabels), np.asarray(gcs.valid).sum())
     for name, (lab, nv) in results.items():
         la, _ = results["sync2"]

@@ -61,7 +61,7 @@ def check_layout(name: str):
                 max_clusters=max_clusters, schedule=schedule,
             )
             run = ddc.make_ddc_fn(mesh, "data", cfg)
-            glabels, gcs, _ = run(x, msk)
+            glabels, gcs, _, _ = run(x, msk)
             assert not bool(np.asarray(gcs.overflow)), (
                 f"{name} k={k} {schedule}: cluster budget overflow")
             labels[schedule] = np.asarray(glabels)

@@ -139,6 +139,33 @@ class TestFacade:
         assert stats["backend"] == "host"
         assert stats["bytes_total"] > 0
 
+    def test_jit_stats_count_phase1_of_every_lane(self):
+        """``DDC.stats()`` on ``jit`` sums each lane's ``Phase1Stats``,
+        the numbers its ``ddc.run`` span lists; ``host`` keeps them 0."""
+        from repro import obs
+
+        pts = layout_points("rings", 512)
+        obs.clear()
+        model = DDC(layout_config("rings", backend="jit", shards=1))
+        for _ in range(2):
+            model.fit(pts).labels_
+        c = model.stats().counters
+        runs = [s for s in obs.spans() if s.name == "ddc.run"]
+        assert len(runs) == 2 and c.refits == 2
+        assert c.phase1_runs == 2
+        assert c.phase1_sweeps == sum(sum(s.attrs["sweeps"]) for s in runs) > 0
+        assert c.phase1_doubling_steps == sum(
+            sum(s.attrs["doubling_steps"]) for s in runs)
+        assert c.phase1_tile_pairs == sum(sum(s.attrs["tile_pairs"]) for s in runs)
+        assert c.phase1_dense_fallbacks == sum(
+            sum(s.attrs["dense_fallback"]) for s in runs)
+        host = DDC(layout_config("rings", backend="host", shards=2)).fit(pts)
+        hc = host.stats().counters
+        assert hc.refits == 1
+        assert (hc.phase1_runs, hc.phase1_sweeps, hc.phase1_doubling_steps,
+                hc.phase1_tile_pairs) == (0, 0, 0, 0)
+        obs.clear()
+
     def test_expire_requires_stream_backend(self):
         model = DDC(layout_config("rings", backend="host", shards=2))
         with pytest.raises(ConfigError, match="stream"):

@@ -107,5 +107,43 @@ def test_module_recorder_and_names():
     (s,) = [s for s in obs.spans() if s.name == "ddc.fit"]
     assert s.attrs == {"n": 1}
     assert set(obs.SPAN_NAMES) == {"ddc.fit", "ddc.ingest", "ddc.refresh",
-                                   "ddc.phase1", "ddc.aggregate", "ddc.live"}
+                                   "ddc.phase1", "ddc.aggregate", "ddc.live",
+                                   "ddc.refit", "ddc.run"}
+    obs.clear()
+
+
+def test_jit_refit_nests_its_run_under_one_trace_per_fit():
+    """The ``jit`` backend's pipeline runs at the first read after a
+    write: one ``ddc.refit`` trace per fit, its ``ddc.run`` child inside
+    it, neither under the ``ddc.fit`` that only split the points."""
+    from repro.data import spatial
+    from repro.ddc import DDC, DDCConfig
+
+    pts, _ = spatial.make_blobs(1024, 5, seed=3)
+    model = DDC(DDCConfig(eps=0.05, min_pts=5, grid=96, max_clusters=16,
+                          max_verts=64, backend="jit", shards=1))
+    obs.clear()
+    for _ in range(2):
+        model.fit(pts)
+        model.labels_
+    model.labels_                                # no write: no refit
+    spans = obs.spans()
+    fits = [s for s in spans if s.name == "ddc.fit"]
+    refits = [s for s in spans if s.name == "ddc.refit"]
+    runs = [s for s in spans if s.name == "ddc.run"]
+    assert len(fits) == len(refits) == len(runs) == 2
+    for fit, refit, run in zip(fits, refits, runs):
+        assert refit.parent_id is None and refit.trace_id == refit.span_id
+        assert refit.trace_id != fit.trace_id and fit.end <= refit.start
+        assert run.parent_id == refit.span_id
+        assert run.trace_id == refit.trace_id
+        assert refit.start <= run.start <= run.end <= refit.end
+        assert refit.attrs == {"backend": "jit", "shards": 1, "cap": 1024}
+        for key in ("sweeps", "doubling_steps", "tile_pairs_active",
+                    "tile_pairs", "dense_fallback"):
+            assert len(run.attrs[key]) == 1, key
+        assert run.attrs["sweeps"][0] >= 1
+        assert run.attrs["overflow"] is False
+        assert run.attrs["truncated"] == 0
+    assert refits[0].trace_id != refits[1].trace_id
     obs.clear()

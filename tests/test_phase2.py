@@ -189,7 +189,7 @@ def _with_axis(fn, cs, cfg, meter):
         lambda c: fn(c, cfg, "data", meter),
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(), cs),),
-        out_specs=(jax.tree.map(lambda _: P(), cs), P()),
+        out_specs=(jax.tree.map(lambda _: P(), cs), P(), P()),
         check_vma=False,
     )
     return wrapped(cs)
