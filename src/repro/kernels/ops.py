@@ -128,7 +128,9 @@ class TilePairs(NamedTuple):
     tile), tail-padded by repeating the last active pair with flags=0.
     flags bit0 = pair is real, bit1 = first pair of its row tile.
     n_active / frac are traced scalars (the pair *values* are data
-    dependent; only shapes are static).
+    dependent; only shapes are static).  The kernels' grid is
+    ``n_active`` steps long — it walks the active pairs only and never
+    visits the tail, which the pair-list references skip by its flags.
     """
 
     rows: jax.Array     # (P,) int32 row-tile index
@@ -194,7 +196,7 @@ def neighbor_count_sparse(x, mask, eps, pairs: TilePairs, *, bt: int = 512) -> j
         return ref.neighbor_count_sparse(x, mask, eps, pairs.rows,
                                          pairs.cols, pairs.flags, bt)
     return _pd.neighbor_count_sparse(x, mask, eps, pairs.rows, pairs.cols,
-                                     pairs.flags, bt=bt,
+                                     pairs.flags, pairs.n_active, bt=bt,
                                      interpret=_interpret())
 
 
@@ -206,8 +208,8 @@ def min_label_sweep_sparse(x, mask, labels, core, eps, pairs: TilePairs, *,
                                           pairs.rows, pairs.cols,
                                           pairs.flags, bt)
     return _pd.min_label_sweep_sparse(x, mask, labels, core, eps, pairs.rows,
-                                      pairs.cols, pairs.flags, bt=bt,
-                                      interpret=_interpret())
+                                      pairs.cols, pairs.flags, pairs.n_active,
+                                      bt=bt, interpret=_interpret())
 
 
 # -- attention ---------------------------------------------------------------

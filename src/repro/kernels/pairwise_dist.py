@@ -38,8 +38,9 @@ DEF_BM = 512
 NO_LABEL = 2**30
 
 # Active-pair flag bits (see ops.build_tile_pairs): bit0 = pair is real
-# (not tail padding), bit1 = first pair of its row tile (output block must
-# be initialised before accumulating).
+# (not tail padding; read by the pair-list references only, since the
+# kernels' grid stops before the tail), bit1 = first pair of its row tile
+# (output block must be initialised before accumulating).
 PAIR_VALID = 1
 PAIR_FIRST = 2
 
@@ -199,54 +200,50 @@ def min_label_sweep(
 
 # ---------------------------------------------------------------------------
 # Block-sparse (gathered-grid) variants — DDC phase 1 on spatially sorted
-# points.  The grid iterates an *active-pair list* (built by
+# points.  The grid walks an *active-pair list* (built by
 # ops.build_tile_pairs from per-tile bounding boxes) instead of the full
 # (T, T) tile product: tile pairs provably farther than eps apart are never
-# fetched or computed.  Scalar-prefetched row/col indices drive the block
-# gather; pairs arrive sorted by row tile so each output block is resident
-# for exactly one contiguous run of grid steps (init on PAIR_FIRST,
-# accumulate while PAIR_VALID, write-back when the row index advances).
+# fetched or computed.  The list keeps its static T² length, but the grid
+# is its active count ``n_active`` (a traced scalar, a dynamic grid bound),
+# so the padded tail past it is never visited.  Scalar-prefetched row/col
+# indices drive the block gather; pairs arrive sorted by row tile so each
+# output block is resident for exactly one contiguous run of grid steps
+# (init on PAIR_FIRST, accumulate, write-back when the row index advances
+# or the grid ends).
 # ---------------------------------------------------------------------------
 
 
 def _count_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
                          x_ref, yt_ref, xm_ref, ym_ref, o_ref):
-    flags = flags_ref[pl.program_id(0)]
-
-    @pl.when((flags & PAIR_FIRST) != 0)
+    @pl.when((flags_ref[pl.program_id(0)] & PAIR_FIRST) != 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when((flags & PAIR_VALID) != 0)
-    def _acc():
-        _count_tile(o_ref, _within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref))
+    _count_tile(o_ref, _within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref))
 
 
 def _min_label_sparse_kernel(rows_ref, cols_ref, flags_ref, eps_sq_ref,
                              x_ref, yt_ref, xm_ref, ym_ref, lab_ref, core_ref,
                              o_ref):
-    flags = flags_ref[pl.program_id(0)]
-
-    @pl.when((flags & PAIR_FIRST) != 0)
+    @pl.when((flags_ref[pl.program_id(0)] & PAIR_FIRST) != 0)
     def _init():
         o_ref[...] = jnp.full(o_ref.shape, NO_LABEL, jnp.int32)
 
-    @pl.when((flags & PAIR_VALID) != 0)
-    def _acc():
-        ok = (_within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref)
-              & (core_ref[...] > 0))
-        _min_label_tile(o_ref, ok, lab_ref)
+    ok = (_within(eps_sq_ref, x_ref, yt_ref, xm_ref, ym_ref)
+          & (core_ref[...] > 0))
+    _min_label_tile(o_ref, ok, lab_ref)
 
 
-def _sparse_call(kernel, n_pairs: int, n: int, d: int, bt: int,
+def _sparse_call(kernel, n_active: jax.Array, n: int, d: int, bt: int,
                  n_col_rows: int, interpret: bool):
-    """pallas_call over the active-pair grid: SMEM eps², the row tile
-    (bt, d), the transposed column tile (d, bt), the row mask (bt, 1) and
-    ``n_col_rows`` column-side (1, bt) operands; output (n, 1)."""
+    """pallas_call over the first ``n_active`` entries of the pair list:
+    SMEM eps², the row tile (bt, d), the transposed column tile (d, bt),
+    the row mask (bt, 1) and ``n_col_rows`` column-side (1, bt) operands;
+    output (n, 1)."""
     col_row = pl.BlockSpec((1, bt), lambda p, r, c, f: (0, c[p]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_pairs,),
+        grid=(n_active,),
         in_specs=[
             _SMEM_SCALAR,
             pl.BlockSpec((bt, d), lambda p, r, c, f: (r[p], 0)),
@@ -266,19 +263,20 @@ def _sparse_call(kernel, n_pairs: int, n: int, d: int, bt: int,
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def neighbor_count_sparse(
     x: jax.Array, mask: jax.Array, eps: float | jax.Array,
-    rows: jax.Array, cols: jax.Array, flags: jax.Array, *,
-    bt: int = DEF_BN, interpret: bool = False,
+    rows: jax.Array, cols: jax.Array, flags: jax.Array, n_active: jax.Array,
+    *, bt: int = DEF_BN, interpret: bool = False,
 ) -> jax.Array:
     """Masked ε-neighbour count over an active tile-pair list.
 
     x: (n, d) spatially sorted, n a multiple of ``bt``; rows/cols/flags:
-    (P,) int32 pair list sorted by row (every row tile appears — the
-    diagonal pair is always active).  Matches the dense ``neighbor_count``
-    bit-exactly when the pair list covers every within-eps tile pair.
+    (P,) int32 pair list sorted by row, of which the first ``n_active``
+    (() int32) are walked — every row tile appears among them, since the
+    diagonal pair is always active.  Matches the dense ``neighbor_count``
+    bit-exactly when those pairs cover every within-eps tile pair.
     """
     n, d = x.shape
     assert n % bt == 0, (n, bt)
-    call = _sparse_call(_count_sparse_kernel, rows.shape[0], n, d, bt, 1,
+    call = _sparse_call(_count_sparse_kernel, n_active, n, d, bt, 1,
                         interpret)
     out = call(rows, cols, flags, _eps_sq(eps), x, x.T, _col(mask),
                _row(mask))
@@ -289,17 +287,19 @@ def neighbor_count_sparse(
 def min_label_sweep_sparse(
     x: jax.Array, mask: jax.Array, labels: jax.Array, core: jax.Array,
     eps: float | jax.Array, rows: jax.Array, cols: jax.Array,
-    flags: jax.Array, *, bt: int = DEF_BN, interpret: bool = False,
+    flags: jax.Array, n_active: jax.Array, *, bt: int = DEF_BN,
+    interpret: bool = False,
 ) -> jax.Array:
     """One min-label propagation sweep over an active tile-pair list.
 
     Same semantics as the dense ``min_label_sweep`` (2**30 where a point
-    has no in-range core neighbour) restricted to listed pairs — identical
-    output when the list covers every within-eps tile pair.
+    has no in-range core neighbour) restricted to the first ``n_active``
+    listed pairs — identical output when they cover every within-eps tile
+    pair.
     """
     n, d = x.shape
     assert n % bt == 0, (n, bt)
-    call = _sparse_call(_min_label_sparse_kernel, rows.shape[0], n, d, bt, 3,
+    call = _sparse_call(_min_label_sparse_kernel, n_active, n, d, bt, 3,
                         interpret)
     out = call(rows, cols, flags, _eps_sq(eps), x, x.T, _col(mask),
                _row(mask), _row(labels), _row(core))

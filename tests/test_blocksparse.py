@@ -104,7 +104,8 @@ class TestKernelEquivalence:
         pairs = ops.build_tile_pairs(x, m, eps, bt=64)
         want = np.asarray(ref.neighbor_count(x, m, eps))
         got = pd.neighbor_count_sparse(x, m, eps, pairs.rows, pairs.cols,
-                                       pairs.flags, bt=64, interpret=True)
+                                       pairs.flags, pairs.n_active, bt=64,
+                                       interpret=True)
         np.testing.assert_array_equal(np.asarray(got), want)
         got_ref = ref.neighbor_count_sparse(x, m, eps, pairs.rows, pairs.cols,
                                             pairs.flags, 64)
@@ -122,13 +123,46 @@ class TestKernelEquivalence:
         pairs = ops.build_tile_pairs(x, m, eps, bt=64)
         want = np.asarray(ref.min_label_sweep(x, m, labels, core, eps))
         got = pd.min_label_sweep_sparse(x, m, labels, core, eps, pairs.rows,
-                                        pairs.cols, pairs.flags, bt=64,
-                                        interpret=True)
+                                        pairs.cols, pairs.flags,
+                                        pairs.n_active, bt=64, interpret=True)
         np.testing.assert_array_equal(np.asarray(got), want)
         got_ref = ref.min_label_sweep_sparse(x, m, labels, core, eps,
                                              pairs.rows, pairs.cols,
                                              pairs.flags, 64)
         np.testing.assert_array_equal(np.asarray(got_ref), want)
+
+    @pytest.mark.parametrize("kernel", ["neighbor_count", "min_label_sweep"])
+    def test_tail_never_visited(self, kernel):
+        """The grid stops at ``n_active``: a tail overwritten with other
+        tiles flagged as real first pairs must not change a single bit."""
+        bt, eps = 512, 0.03
+        x, m = sorted_inputs(RNG.uniform(0, 1, (4096, 2)).astype(np.float32),
+                             np.ones(4096, bool), bt)
+        n = x.shape[0]
+        t = n // bt
+        labels = jnp.asarray(RNG.permutation(n), jnp.int32)
+        core = jnp.asarray(RNG.random(n) > 0.4)
+        pairs = ops.build_tile_pairs(x, m, eps, bt=bt)
+        k = int(pairs.n_active)
+        assert t <= k < t * t
+        tail = np.arange(t * t - k)
+        poison = lambda a, v: jnp.asarray(a).at[k:].set(v)
+        rows = poison(pairs.rows, (tail * 5) % t)
+        cols = poison(pairs.cols, (tail * 3 + 1) % t)
+        flags = poison(pairs.flags, pd.PAIR_VALID | pd.PAIR_FIRST)
+        if kernel == "neighbor_count":
+            run = lambda r, c, f: pd.neighbor_count_sparse(
+                x, m, eps, r, c, f, pairs.n_active, bt=bt, interpret=True)
+            want = ref.neighbor_count(x, m, eps)
+        else:
+            run = lambda r, c, f: pd.min_label_sweep_sparse(
+                x, m, labels, core, eps, r, c, f, pairs.n_active, bt=bt,
+                interpret=True)
+            want = ref.min_label_sweep(x, m, labels, core, eps)
+        clean = np.asarray(run(pairs.rows, pairs.cols, pairs.flags))
+        np.testing.assert_array_equal(clean, np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(run(rows, cols, flags)),
+                                      clean)
 
 
 class TestDBSCANBlockSparse:

@@ -3,10 +3,11 @@ described, not attached — what interpret mode cannot check: the (8, 128)
 tiling rule, 2-D vector layouts and VMEM limits.
 
 Widths are the deployment's: a 65,536-point phase-1 shard in 512-point
-tiles (the block-sparse grids at their full T² length) and a 128-slot ×
-128-vertex phase-2 merge matrix.  The topology is described inside a
-fixture, never at import, so every test worker collects the same tests
-and only the worker given this file loads the TPU compiler.
+tiles (the block-sparse pair lists at their full T² length, walked by a
+grid as long as the active count) and a 128-slot × 128-vertex phase-2
+merge matrix.  The topology is described inside a fixture, never at
+import, so every test worker collects the same tests and only the worker
+given this file loads the TPU compiler.
 """
 import jax
 import jax.numpy as jnp
@@ -46,15 +47,21 @@ def _kernel_case(name, shape):
     x, mask, ints, eps = shape((N, 2), f32), shape((N,), b), shape((N,), i32), \
         shape((), f32)
     pairs = (shape((PAIRS,), i32),) * 3
+    n_active = shape((), i32)
     cases = {
         "neighbor_count": (pd.neighbor_count, (x, mask, eps)),
         "min_label_sweep": (pd.min_label_sweep, (x, mask, ints, mask, eps)),
         "neighbor_count_sparse": (
-            lambda *a: pd.neighbor_count_sparse(*a, bt=BT),
+            lambda *a: pd.neighbor_count_sparse(*a, PAIRS, bt=BT),
             (x, mask, eps) + pairs),
         "min_label_sweep_sparse": (
-            lambda *a: pd.min_label_sweep_sparse(*a, bt=BT),
+            lambda *a: pd.min_label_sweep_sparse(*a, PAIRS, bt=BT),
             (x, mask, ints, mask, eps) + pairs),
+        # The grid bound as the phase-1 path gives it: a traced scalar.
+        "sparse_traced_grid": (
+            lambda *a: (pd.neighbor_count_sparse(a[0], a[1], a[4], *a[5:], bt=BT),
+                        pd.min_label_sweep_sparse(*a, bt=BT)),
+            (x, mask, ints, mask, eps) + pairs + (n_active,)),
         "contour_min_d2": (
             lambda p, v: cd.contour_min_d2(p, v, VERTS),
             (shape((SLOTS * VERTS, 2), f32), shape((SLOTS * VERTS,), i32))),
@@ -64,7 +71,7 @@ def _kernel_case(name, shape):
 
 @pytest.mark.parametrize("name", [
     "neighbor_count", "min_label_sweep", "neighbor_count_sparse",
-    "min_label_sweep_sparse", "contour_min_d2",
+    "min_label_sweep_sparse", "sparse_traced_grid", "contour_min_d2",
 ])
 def test_kernel_compiles_for_v5e(one_chip, name):
     def shape(s, dtype):
