@@ -171,13 +171,12 @@ def make_noise_heavy(n: int = 2048, seed: int = 4,
         np.clip(np.concatenate(parts + [noise]), 0, 1).astype(np.float32))
 
 
-# Phase-2 benchmark/test layout registry: generator + the DDC parameters
-# (eps, min_pts, grid, max_verts, max_clusters) tuned so every local AND
+# Phase-2 layout registry: generator + the DDC parameters (eps,
+# min_pts, grid, max_verts, max_clusters) tuned so every local AND
 # merged contour fits the vertex budget at 2–32 shards and inter-cluster
 # gaps clear both merge predicates with margin (DESIGN.md §7 sizing
-# rule).  benchmarks/phase2.py and tests/_phase2_script.py consume this
-# single table so the benchmark and the equivalence suite can never
-# drift onto different configurations.
+# rule).  The serve driver and the equivalence suites consume this
+# single table so they can never drift onto different configurations.
 PHASE2_LAYOUTS = {
     "rings": dict(make=make_rings, eps=0.008, min_pts=5,
                   grid=64, max_verts=80, max_clusters=8),
@@ -199,7 +198,7 @@ def shard_capacity(n: int, shards: int) -> int:
     """Ring slots per shard so a block partition of ``n`` points fits
     exactly: the largest ``np.array_split`` part, i.e. ceil(n/shards).
     The one sizing rule shared by the stream backend, the serve
-    benchmarks/launchers, and the equivalence tests."""
+    driver, and the equivalence tests."""
     return max(-(-n // shards), 1)
 
 
@@ -361,8 +360,8 @@ def make_convoys(steps: int = 20, n_per_step: int = 96, seed: int = 2,
 # like PHASE2_LAYOUTS: contours fit the vertex budget at 2-8 shards,
 # inter-group gaps clear the merge radius (eps + 1.5*cell ≈ 0.051), and
 # the per-step displacement stays well inside the match gate so
-# continuations are unambiguous.  benchmarks/tracking.py and
-# tests/test_tracking.py consume this single table.
+# continuations are unambiguous.  The serve driver's ``--mode track``
+# and tests/test_tracking.py consume this single table.
 TRAJECTORY_LAYOUTS = {
     "drifting_blobs": dict(make=make_drifting_blobs, eps=0.02, min_pts=3,
                            grid=48, max_verts=96, max_clusters=8,

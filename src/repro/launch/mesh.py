@@ -1,10 +1,7 @@
-"""Production mesh builders.
+"""Mesh builders.
 
-A function (not a module constant) so importing never touches jax device
-state.  Single pod: 256 chips as (data=16, model=16); multi-pod: 2 pods
-= 512 chips as (pod=2, data=16, model=16) — 'pod' is the outer DP axis
-(its collectives cross the inter-pod DCN/ICI links, which is what the
-multi-pod dry-run exercises).
+Functions (not module constants) so importing never touches jax device
+state.
 """
 from __future__ import annotations
 
@@ -34,12 +31,6 @@ def device_shortfall(needed: int) -> str | None:
         return msg + (f"set XLA_FLAGS=--xla_force_host_platform_device_count="
                       f"{needed} before jax initialises (or lower shards)")
     return msg + f"run on a host with >= {needed} chips (or lower shards)"
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n: int | None = None, axis: str = "data"):

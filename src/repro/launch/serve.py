@@ -1,37 +1,35 @@
-"""Serving drivers.
+"""Serving driver for the streaming spatial-clustering service.
 
 Two modes behind one entry point:
 
-* ``--mode lm`` (default) — batched LM request loop over prefill + decode.
-* ``--mode ddc`` — the streaming spatial-clustering service: ingest a
-  synthetic layout shard-by-shard with an incremental delta-merge
-  refresh after every batch, then serve point->cluster queries.
+* ``--mode ddc`` (default) — ingest a synthetic layout shard-by-shard
+  with an incremental delta-merge refresh after every batch, then serve
+  point->cluster queries.
 * ``--mode track`` — the cluster-tracking subsystem (DESIGN.md §14):
   play a seeded trajectory stream (``--layout`` from
   ``TRAJECTORY_LAYOUTS``, default ``drifting_blobs``) through a
   ``track=True`` deployment with sliding-window eviction, then print
   the per-track IDs, velocities/headings, motion classes, and the
   lifecycle-event census as a JSON line.
-  ``--backend stream`` (default) is the host-driven engine
-  (serve/cluster_service.py); ``--backend dist`` pins each shard's
-  buffers to its own mesh device (serve/dist_service.py) so the printed
-  comm volume is real axis-crossing bytes.  Prints a JSON line of
-  ingest/query latency, delta-path comm volume, and query-routing
-  counters.
 
-  ``--qps-requests N`` appends the pipelined high-QPS request loop
-  (DESIGN.md §12): N requests flow through the bounded ``QueryTier``
-  queue — coalesced into batched snapshot reads — while the tail of the
-  ingest stream keeps writing and republishing under them, so the
-  printed p50/p99/QPS measures decoupled snapshot serving, not
-  refresh-blocked reads.
+``--backend stream`` (default) is the host-driven engine
+(serve/cluster_service.py); ``--backend dist`` pins each shard's
+buffers to its own mesh device (serve/dist_service.py) so the printed
+comm volume is real axis-crossing bytes.  ``--mode ddc`` prints a JSON
+line of ingest/query latency, delta-path comm volume, and
+query-routing counters.
+
+``--qps-requests N`` appends the pipelined high-QPS request loop
+(DESIGN.md §12): N requests flow through the bounded ``QueryTier``
+queue — coalesced into batched snapshot reads — while the tail of the
+ingest stream keeps writing and republishing under them, so the
+printed p50/p99/QPS measures decoupled snapshot serving, not
+refresh-blocked reads.
 
 CPU-scale examples:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --tiny \
-      --requests 4 --prompt-len 32 --gen 16
-  PYTHONPATH=src python -m repro.launch.serve --mode ddc --layout rings \
+  PYTHONPATH=src python -m repro.launch.serve --layout rings \
       --shards 8 --queries 512
-  PYTHONPATH=src python -m repro.launch.serve --mode ddc --backend dist \
+  PYTHONPATH=src python -m repro.launch.serve --backend dist \
       --shards 8 --qps-requests 64 --deadline-ms 50
   PYTHONPATH=src python -m repro.launch.serve --mode track \
       --layout merging_crowds --shards 4
@@ -62,7 +60,6 @@ if __name__ == "__main__":
             + f" --xla_force_host_platform_device_count={_ns.shards}"
         ).strip()
 
-import jax
 import numpy as np
 
 
@@ -71,17 +68,9 @@ def main(argv=None):
 
     compile_cache.enable()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("lm", "ddc", "track"), default="lm")
-    # LM mode
-    ap.add_argument("--arch")
-    ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--requests", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--mesh-devices", type=int, default=0)
-    ap.add_argument("--seed", type=int, default=0)
-    # DDC streaming mode
+    ap.add_argument("--mode", choices=("ddc", "track"), default="ddc")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the query and request points")
     ap.add_argument("--layout", default="rings",
                     help="a data/spatial.py PHASE2_LAYOUTS name (--mode "
                          "ddc) or TRAJECTORY_LAYOUTS name (--mode track, "
@@ -118,13 +107,9 @@ def main(argv=None):
                     help="trajectory frames to play (--mode track; "
                          "0: the layout's default)")
     args = ap.parse_args(argv)
-    if args.mode == "ddc":
-        return serve_ddc(args)
     if args.mode == "track":
         return serve_track(args)
-    if not args.arch:
-        ap.error("--arch is required for --mode lm")
-    return serve_lm(args)
+    return serve_ddc(args)
 
 
 def serve_ddc(args):
@@ -324,50 +309,6 @@ def _request_loop(model, writes, args, rng):
         "deadline_misses": c["deadline_misses"],
         "queue_depth": tier.queue_depth,
     }
-
-
-def serve_lm(args):
-    from repro import configs
-    from repro.launch import mesh as mesh_mod
-    from repro.parallel import api as par
-    from repro.serve import engine
-
-    cfg = configs.get_config(args.arch)
-    if args.tiny:
-        cfg = cfg.tiny()
-    n = args.mesh_devices or len(jax.devices())
-    mesh = mesh_mod.make_host_mesh(n) if n > 1 else None
-    pctx = par.ParallelCtx(mesh=mesh)
-
-    key = jax.random.PRNGKey(args.seed)
-    params = __import__("repro.models.transformer", fromlist=["x"]).init_params(cfg, key)
-    scfg = engine.ServeConfig(max_len=args.prompt_len + args.gen + cfg.prefix_len)
-
-    prompts = jax.random.randint(key, (args.requests, args.prompt_len), 0, cfg.vocab)
-    kw = {}
-    if cfg.frontend == "audio_stub":
-        kw["frames"] = jax.random.normal(
-            key, (args.requests, cfg.frontend_seq, cfg.d_model)) * 0.1
-    if cfg.prefix_len:
-        kw["prefix"] = jax.random.normal(
-            key, (args.requests, cfg.prefix_len, cfg.d_model)) * 0.1
-
-    t0 = time.time()
-    out = engine.greedy_generate(
-        cfg, params, prompts, args.gen, scfg, pctx,
-        temperature=args.temperature, key=key if args.temperature > 0 else None,
-        **kw,
-    )
-    dt = time.time() - t0
-    toks = args.requests * args.gen
-    print(json.dumps({
-        "requests": args.requests,
-        "generated_tokens": toks,
-        "wall_s": round(dt, 3),
-        "tok_per_s": round(toks / dt, 2),
-        "sample_output": np.asarray(out[0][:8]).tolist(),
-    }))
-    return out
 
 
 if __name__ == "__main__":

@@ -1,8 +1,7 @@
-"""Serving substrates: the LM prefill/decode engine (engine.py) and the
-streaming DDC cluster services (cluster_service.py: host-mirror control
-plane + host-driven data plane; dist_service.py: the same control plane
-over a device-resident shard_map data plane).  faults.py / journal.py
-are the failure model riding both (DESIGN.md §11): seeded fault
+"""The streaming DDC cluster services (cluster_service.py: host-mirror
+control plane + host-driven data plane; dist_service.py: the same
+control plane over a device-resident shard_map data plane).
+faults.py / journal.py are the failure model riding both (DESIGN.md §11): seeded fault
 injection, the delta validation gate, and the write-ahead recovery log.
 query_tier.py is the high-QPS read path riding on top (DESIGN.md §12):
 immutable versioned snapshots published at refresh, coalesced batched
@@ -13,8 +12,8 @@ tracking.py is the cluster tracking subsystem (DESIGN.md §14): stable
 track IDs, lifecycle events, and motion analytics folded over the
 refresh generations of either engine.
 
-The cluster-service re-exports are lazy (PEP 562) so importing the LM
-engine does not drag in the whole clustering stack, and vice versa.
+The re-exports are lazy (PEP 562): importing one submodule does not
+drag in the others.
 """
 
 _CLUSTER_EXPORTS = ("ClusterService", "ShardControlPlane", "StreamConfig")

@@ -67,7 +67,7 @@ distinct nodes.  A full re-merge ships all K ClusterSets up
 the dirty ones (|dirty|·B).  Both ship each shard its (C,) slot-map row
 back down (K·C·4 bytes).  Steady-state single-shard ingest therefore
 moves B + K·C·4 per refresh vs K·B + K·C·4 — the measurable
-minimal-communication claim (benchmarks/serve.py).  For this host-driven
+minimal-communication claim (tests/test_serve_stream.py).  For this host-driven
 engine the model is metered; the ``dist`` data plane realises the same
 byte counts as real device-boundary transfers.
 """

@@ -76,8 +76,7 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, pctx: par.ParallelCtx)
     """Returns (step_fn, state_shardings, batch_sharding_fn).
 
     step_fn(state, batch) -> (state, metrics); jit-with-shardings happens
-    in the caller (launch/train.py or launch/dryrun.py) so dry-runs can
-    .lower() without allocating."""
+    in the caller so a dry-run can .lower() without allocating."""
 
     grad_shardings = None
     if pctx.mesh is not None:

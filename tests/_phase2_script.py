@@ -24,10 +24,9 @@ SHARD_COUNTS = (2, 4, 8, 16)
 # Per-layout DDC parameters (eps, min_pts, grid, max_verts, max_clusters):
 # tuned so no local OR merged contour overflows its vertex budget at any
 # shard count and inter-cluster gaps clear both merge predicates with
-# margin — see DESIGN.md §7.  The phase-2 benchmark layouts come from
-# the shared spatial.PHASE2_LAYOUTS table (same tuning as
-# benchmarks/phase2.py); the remaining data/spatial.py generators get
-# their own tuples here.
+# margin — see DESIGN.md §7.  The phase-2 layouts come from the shared
+# spatial.PHASE2_LAYOUTS table; the remaining data/spatial.py generators
+# get their own tuples here.
 CASES = {
     "blobs": (lambda: spatial.make_blobs(1024, 5, seed=0, spread=0.015)[0],
               0.05, 5, 96, 48, 12),

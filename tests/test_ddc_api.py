@@ -15,7 +15,7 @@ The API contract under test:
 * a query against a fresh service returns all-noise without compiling
   or refreshing anything.
 
-Big sweeps are marked ``slow`` (non-blocking CI job).
+Big sweeps are marked ``slow`` (the separate tier1-slow CI job).
 """
 import os
 import subprocess

@@ -43,14 +43,17 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def build_pair(layout: str, k: int, degree: int):
-    """A flat service and a tree-of-aggregators twin on the same layout."""
+    """A flat service and a tree-of-aggregators twin on the same layout.
+    ``max_batch`` is capped at the shard capacity: the ingest ring
+    rejects a batch larger than itself (16 shards of N leave 128 slots)."""
     spec = spatial.PHASE2_LAYOUTS[layout]
     pts = spec["make"](N)
     cap = spatial.shard_capacity(N, k)
     flat = ClusterService(StreamConfig(
-        shards=k, capacity=cap, max_batch=256, ddc=layout_cfg(spec)))
+        shards=k, capacity=cap, max_batch=min(256, cap),
+        ddc=layout_cfg(spec)))
     hier = ClusterService(StreamConfig(
-        shards=k, capacity=cap, max_batch=256, agg_degree=degree,
+        shards=k, capacity=cap, max_batch=min(256, cap), agg_degree=degree,
         ddc=layout_cfg(spec)))
     return flat, hier, pts, spec
 
@@ -201,6 +204,68 @@ class TestHierEqualsFlatStream:
                 for svc in (flat, hier):
                     stream(svc, pts, k)
                 assert_equiv(flat, hier)
+
+
+class TestTreeRefreshBytes:
+    """The reason DESIGN §13 gives for the tree: at K = 32 a steady-state
+    single-dirty refresh (one shard re-ingested, global structure
+    unchanged) moves fewer bytes through the tree than through the flat
+    aggregator, whose down-leg broadcasts a (C,) map row to every shard.
+
+    Workload: 8 well-separated blobs, contiguous partition (a subtree
+    covers a contiguous point range), small slot budgets."""
+
+    K = 32
+    CFG = ddc.DDCConfig(eps=0.03, min_pts=3, grid=48,
+                        max_clusters=8, max_verts=24)
+
+    @staticmethod
+    def blobs(n: int = 8192, seed: int = 0) -> np.ndarray:
+        rng = np.random.default_rng(seed)
+        centers = [(0.18 + 0.32 * (i % 3), 0.18 + 0.32 * (i // 3))
+                   for i in range(8)]
+        pts = np.concatenate([
+            c + rng.normal(scale=0.018, size=(n // 8, 2)) for c in centers])
+        return np.clip(pts, 0.01, 0.99).astype(np.float32)
+
+    @pytest.fixture(scope="class")
+    def flat(self):
+        """The (K, C) shard batch and the flat arm after one steady
+        single-dirty ``merge_delta`` refresh."""
+        cfg, k = self.CFG, self.K
+        slices = np.array_split(self.blobs(), k)
+        cap = max(len(s) for s in slices)
+        sets = []
+        for sl in slices:
+            buf = np.zeros((cap, 2), np.float32)
+            buf[:len(sl)] = sl
+            mask = np.arange(cap) < len(sl)
+            _, cs = ddc.local_phase(jnp.asarray(buf), jnp.asarray(mask), cfg)
+            sets.append(cs)
+        batch = jax.tree.map(lambda *xs: jnp.stack(xs), *sets)
+        _, _, d2 = ddc.merge_delta(batch, None, None, cfg, None)
+        _, maps, _ = ddc.merge_delta(batch, d2, [0], cfg, None)
+        return batch, np.asarray(maps)
+
+    @pytest.mark.parametrize("degree", [2, 4])
+    def test_tree_refresh_moves_fewer_bytes_than_flat(self, flat, degree):
+        batch, flat_maps = flat
+        cfg, k = self.CFG, self.K
+        bbytes, row = cfg.buffer_bytes(), cfg.max_clusters * 4
+        tree = AggregatorTree(k, degree, cfg)
+        tree.refresh(batch, None, None)
+        tree.refresh(batch, [0], None)
+        _, maps = tree.refresh(batch, [0], None)
+        s = tree.last_stats
+        # Wire model: a ClusterSet per dirty shard payload and per internal
+        # summary push; a (C,) i32 row per down map edge and changed shard.
+        tree_bytes = ((s["up_shard_payloads"] + s["internal_up_edges"]) * bbytes
+                      + (s["down_internal_edges"] + s["down_shard_rows"]) * row)
+        # Flat: one shard payload up, the engine's K-row map broadcast down.
+        flat_bytes = bbytes + k * row
+        np.testing.assert_array_equal(np.asarray(maps), flat_maps)
+        assert tree_bytes < flat_bytes
+        assert (tree_bytes, flat_bytes) == (1609, 2633)
 
 
 class TestFacade:

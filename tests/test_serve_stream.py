@@ -9,8 +9,8 @@ delta-merge internals (cached matrix == from-scratch matrix), the comm
 accounting of delta vs full re-merge, and the eviction regressions
 (emptied shard -> cached ``empty_clusterset`` path; ring overwrite).
 
-Big sweeps are marked ``slow`` (separate non-blocking CI job); the
-unmarked subset keeps the blocking tier-1 run light.
+Big sweeps are marked ``slow`` (the separate tier1-slow CI job); the
+unmarked subset keeps the tier-1 CI job light.
 """
 import json
 

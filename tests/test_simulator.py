@@ -50,8 +50,7 @@ class TestSpeedup:
     def test_super_linear_speedup(self):
         """§5.5: O(n^2) local algorithm ⇒ speedup beyond machine count.
         Cleanest statement on a homogeneous 8-machine cluster; the paper
-        measures 9x on its heterogeneous 8 (reproduced in
-        benchmarks/speedup.py with their T1 convention)."""
+        measures 9x on its heterogeneous 8 with its own T1 convention."""
         n = 10_000
         homog = [dataclasses.replace(MACH[0], name=f"m{i}") for i in range(8)]
         t1 = sim.sequential_time(MACH[0], n)
